@@ -13,16 +13,23 @@ from __future__ import annotations
 import argparse
 import json
 import pathlib
+import sys
 import time
 
 import jax
 
 REPO_ROOT = pathlib.Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(REPO_ROOT / "src"))
+
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def add_common_args(p: argparse.ArgumentParser, *, iters: int,
                     backend: str = "xla") -> argparse.ArgumentParser:
-    """The flags every benchmark shares: --iters, --backend, --smoke."""
+    """The flags every benchmark shares: --iters, --backend, --smoke.
+    Every benchmark's ``main`` builds its parser here, so this is also
+    where the benchmarks turn on the persistent compilation cache."""
+    enable_compile_cache()
     p.add_argument("--iters", type=int, default=iters)
     p.add_argument("--backend", type=str, default=backend,
                    choices=("xla", "pallas", "auto"))

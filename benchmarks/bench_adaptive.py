@@ -27,8 +27,6 @@ import numpy as np
 
 import _harness as H
 
-sys.path.insert(0, str(H.REPO_ROOT / "src"))
-
 from repro.core.adaptive import GovernorConfig  # noqa: E402
 from repro.core.pipeline import ADAPTIVE_ARMS, StreamingAggregator  # noqa: E402
 from repro.core.types import ExecConfig, empty_key  # noqa: E402

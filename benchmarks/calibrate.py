@@ -20,7 +20,6 @@ from __future__ import annotations
 
 import argparse
 import pprint
-import sys
 import tempfile
 
 import jax
@@ -28,8 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 
 import _harness as H
-
-sys.path.insert(0, str(H.REPO_ROOT / "src"))
 
 from repro.core import cost_model, hash_agg, sorted_ops  # noqa: E402
 from repro.core.pipeline import POLICIES, StreamingAggregator  # noqa: E402
@@ -149,9 +146,10 @@ def calibrate(args) -> dict:
         cfg = ExecConfig(memory_rows=256, page_rows=32, fanin=4, batch_rows=64)
         n_rows, iters = 2_048, 1
     else:
-        cfg = ExecConfig(memory_rows=4096, page_rows=512, fanin=8,
-                         batch_rows=1024)
-        n_rows, iters = 65_536, args.iters
+        cfg = ExecConfig(memory_rows=args.memory_rows,
+                         page_rows=min(512, args.memory_rows), fanin=8,
+                         batch_rows=args.batch_rows)
+        n_rows, iters = args.rows, args.iters
     M = cfg.memory_rows
 
     absorb, absorb_dup = {}, {}
@@ -209,6 +207,12 @@ def main(argv=None):
                    help="write the regenerated constants module here "
                         "(default: the checked-in core/_cost_constants.py; "
                         "--smoke defaults to a temp file)")
+    p.add_argument("--memory-rows", type=int, default=4096,
+                   help="ExecConfig.memory_rows the constants are fitted at")
+    p.add_argument("--batch-rows", type=int, default=1024,
+                   help="ExecConfig.batch_rows (also the streamed chunk)")
+    p.add_argument("--rows", type=int, default=65_536,
+                   help="rows streamed per absorb measurement")
     p.add_argument("--check", action="store_true",
                    help="only validate the checked-in constants against "
                         "the generator schema, then exit")

@@ -34,4 +34,23 @@ COST_CONSTANTS = {'cpu': {'absorb_dup_row_ns': {'early_agg': 2153.875,
                   'smoke': False},
          'schema_version': 1,
          'sort_row_ns': 43.155,
-         'spill_write_row_ns': 16.976}}
+         'spill_write_row_ns': 16.976},
+ 'tpu': {'absorb_dup_row_ns': {'early_agg': 1158.375,
+                               'inrun_dedup': 227.814,
+                               'rs': 5175.396,
+                               'traditional': 55.364},
+         'absorb_row_ns': {'early_agg': 1169.742,
+                           'inrun_dedup': 226.649,
+                           'rs': 6263.828,
+                           'traditional': 53.407},
+         'hash_probe_row_ns': 224.672,
+         'merge_row_ns': 422.357,
+         'meta': {'backend': 'tpu',
+                  'dispatch_backend': 'xla',
+                  'generated_by': 'benchmarks/calibrate.py',
+                  'note': 'memory_rows=524288 batch_rows=524288 '
+                          'n_rows=2097152 iters=2',
+                  'smoke': False},
+         'schema_version': 1,
+         'sort_row_ns': 2.216,
+         'spill_write_row_ns': 1.534}}

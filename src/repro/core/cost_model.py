@@ -275,15 +275,25 @@ def validate_constants(table: dict, *, source: str = "core/_cost_constants.py"):
 
 
 def load_cost_constants(backend: str | None = None) -> dict:
-    """The calibrated constants entry for ``backend`` (falling back to
-    the committed ``cpu`` defaults for uncalibrated backends)."""
+    """The calibrated constants entry for ``backend`` (a JAX platform
+    name; ``None`` is the default backend).  A platform with no entry is
+    an error: another platform's timings would steer plans and policy
+    choices wrongly."""
     from repro.core import _cost_constants as cc
 
     validate_constants(cc.COST_CONSTANTS)
     table = cc.COST_CONSTANTS
-    if backend in table:
-        return table[backend]
-    return table["cpu"]
+    if backend is None:
+        import jax
+
+        backend = jax.default_backend()
+    if backend not in table:
+        raise KeyError(
+            f"no calibrated cost constants for backend {backend!r} (have "
+            f"{sorted(table)}); run `python benchmarks/calibrate.py` on "
+            "that platform and commit its entry"
+        )
+    return table[backend]
 
 
 def _spill_fraction(policy: str, dup_rate: float) -> float:

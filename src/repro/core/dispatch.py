@@ -8,10 +8,9 @@ with ad-hoc lazy imports.  This module centralizes that plumbing:
   on first use and may raise :class:`BackendUnavailable` (capability
   probing: e.g. the Pallas backend probes its kernel imports).
 * ``get_backend(name)`` — resolves a name (or ``"auto"``) to a cached
-  :class:`Backend`.  ``"auto"`` prefers Pallas on TPU and XLA elsewhere.
+  :class:`Backend`.  ``"auto"`` is ``"xla"`` on every platform.
 * ``should_interpret()`` — the single source of truth for Pallas
-  ``interpret=`` mode: interpret everywhere except on real TPU, with an
-  explicit ``REPRO_PALLAS_INTERPRET`` env override for experiments.
+  ``interpret=`` mode: interpret everywhere except on a TPU.
 
 Built-in backends:
 
@@ -23,7 +22,6 @@ Built-in backends:
 from __future__ import annotations
 
 import dataclasses
-import os
 from typing import Callable
 
 import jax
@@ -64,8 +62,7 @@ class Backend:
         Whether the backend's primitives may be traced inside a
         ``shard_map`` manual-collective region (the mesh-sharded
         pipeline runs the whole engine per shard).  Capability flag, not
-        a promise of speed — interpret-mode Pallas is shardable but
-        slow off-TPU.
+        a promise of speed.
     """
 
     name: str
@@ -105,24 +102,13 @@ def backend_available(name: str) -> bool:
         return False
 
 
-def _auto_order() -> tuple[str, ...]:
-    # On TPU the Pallas kernels are the fast path; everywhere else they
-    # run in interpret mode and the XLA engine wins.
-    if jax.default_backend() == "tpu":
-        return ("pallas", "xla")
-    return ("xla", "pallas")
-
-
 def get_backend(name: str = "xla") -> Backend:
-    """Resolve a backend name (or ``"auto"``) to a Backend instance."""
+    """Resolve a backend name to a Backend instance.  ``"auto"`` (or
+    None) is the XLA engine on every platform: no Pallas kernel has yet
+    beaten it on a chip, and a backend that cannot run raises instead of
+    handing the query to another one."""
     if name in ("auto", None):
-        last: Exception | None = None
-        for cand in _auto_order():
-            try:
-                return get_backend(cand)
-            except (KeyError, BackendUnavailable) as e:  # keep probing
-                last = e
-        raise BackendUnavailable(f"no usable backend among {_auto_order()}: {last}")
+        name = "xla"
     if name in _cache:
         return _cache[name]
     if name not in _loaders:
@@ -150,11 +136,22 @@ def check_shardable(name: str) -> None:
         )
 
 
+def checks_vma(name: str) -> bool:
+    """Whether a ``shard_map`` running backend ``name`` per shard keeps
+    JAX's varying-manual-axes check on: everywhere except for Pallas
+    kernels in interpret mode.  JAX's Pallas interpreter evaluates a
+    kernel by binding its primitives one by one, so the kernel's own
+    constants (iotas, literals) meet per-shard blocks and the check
+    refuses ("Primitive le requires varying manual axes to match, but
+    got [frozenset({'data'}), frozenset()] ... as a temporary
+    workaround pass the check_vma=False argument to `jax.shard_map`").
+    Compiled for a TPU, the kernels run per shard with the check on."""
+    return not (get_backend(name).name == "pallas" and should_interpret())
+
+
 def should_interpret() -> bool:
-    """Pallas interpret mode: True off-TPU, overridable via env."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env.lower() not in ("0", "false", "no")
+    """Pallas interpret mode: exactly when JAX's default backend is not a
+    TPU.  Read at trace time, so a TPU never runs the interpreter."""
     return jax.default_backend() != "tpu"
 
 
@@ -172,11 +169,36 @@ def _load_xla() -> Backend:
     )
 
 
+def _probe_tpu_kernels(kops) -> None:
+    """Compile every kernel once at a small tile; a kernel the TPU
+    compiler refuses makes the whole backend unavailable, quoting it."""
+    import jax.numpy as jnp
+
+    from repro.core.types import rows_to_state
+
+    keys = jnp.arange(256, dtype=jnp.uint32)
+    state = rows_to_state(keys, jnp.ones((256, 1), jnp.float32))
+    for name, fn, args in (
+        ("argsort", kops.argsort_keys, (keys,)),
+        ("segmented_combine", kops.segmented_combine, (state,)),
+        ("merge_sorted", kops.merge_absorb_sorted, (state, state)),
+        ("join_probe", kops.join_probe, (keys, keys)),
+    ):
+        try:
+            jax.jit(fn).lower(*args).compile()
+        except (jax.errors.JaxRuntimeError, NotImplementedError, ValueError) as e:
+            raise BackendUnavailable(
+                f"pallas {name} kernel does not compile for this TPU: {e}"
+            ) from e
+
+
 def _load_pallas() -> Backend:
     try:
         from repro.kernels import ops as kops
     except Exception as e:  # missing pallas / mosaic in this build
         raise BackendUnavailable(f"pallas kernels unavailable: {e}") from e
+    if not should_interpret():
+        _probe_tpu_kernels(kops)
     return Backend(
         name="pallas",
         argsort=kops.argsort_keys,

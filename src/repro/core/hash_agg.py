@@ -104,6 +104,16 @@ def _checked_hash(keys: np.ndarray) -> np.ndarray:
 
 
 def _in_memory_agg(keys_h, payload, backend, widths):
+    """Aggregate one hash partition.  Partitions are EMPTY-padded to a
+    power of two so that their many sizes share a few compilations."""
+    n = len(keys_h)
+    pad = (1 << max(0, (n - 1).bit_length())) - n
+    if pad:
+        keys_h = np.concatenate(
+            [keys_h, np.full(pad, empty_key(keys_h.dtype), keys_h.dtype)])
+        if payload is not None:
+            payload = jnp.concatenate(
+                [payload, jnp.zeros((pad,) + payload.shape[1:], payload.dtype)])
     return sorted_ops.sorted_groupby(keys_h, payload, backend=backend, widths=widths)
 
 

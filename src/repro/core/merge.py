@@ -35,6 +35,7 @@ from repro.core.types import (
     empty_like,
     empty_state,
     key_dtype_context,
+    match_vma,
     slice_rows,
 )
 
@@ -322,7 +323,7 @@ def wide_merge_device(
         overflow = overflow | (resident > index_rows)
         return (cursors, index, out, out_cur, pages_read + 1, max_occ, overflow)
 
-    carry = (
+    carry = match_vma((
         jnp.zeros((R,), jnp.int32),
         empty_state(W, width, key_dtype=kd, widths=widths),
         out0,
@@ -330,7 +331,7 @@ def wide_merge_device(
         jnp.int32(0),
         jnp.int32(0),
         jnp.bool_(False),
-    )
+    ), (store_state, lens))
     cursors, index, out, out_cur, pages_read, max_occ, overflow = jax.lax.while_loop(
         cond, body, carry
     )

@@ -84,6 +84,7 @@ from repro.core.types import (
     empty_state,
     expand_engine_scalars,
     key_dtype_context,
+    match_vma,
     max_key,
     rows_to_state,
     squeeze_engine_scalars,
@@ -344,8 +345,8 @@ def _step_rs(es: StreamEngineState, ck, cp, *, M: int, B: int, backend: str,
 
     def overflow_step(s):
         return jax.lax.cond(
-            (s.table.occupancy() == 0) | (s.cursor + B > C), close_fn,
-            evict_fn, s,
+            (s.table.occupancy() == 0) | (s.cursor + B > C),
+            lambda s: match_vma(close_fn(s), s), evict_fn, s,
         )
 
     def overflow_cond(s):
@@ -594,13 +595,13 @@ def _pipeline_body(
     width = 0 if payload is None else payload.shape[-1]
     ws = widths if widths is not None else (width, width, width)
     R = _stream_run_slots(policy, n_pad, M)
-    es = _engine_init(policy, M=M, B=B, P=P, R=R, width=width,
-                      key_dtype=keys.dtype, widths=ws)
+    es = match_vma(_engine_init(policy, M=M, B=B, P=P, R=R, width=width,
+                                key_dtype=keys.dtype, widths=ws), keys)
 
     def body(carry, xs):
         ck, cp = xs
-        return _engine_step(carry, ck, cp, policy=policy, M=M, B=B,
-                            backend=backend, ws=ws), None
+        return match_vma(_engine_step(carry, ck, cp, policy=policy, M=M, B=B,
+                                      backend=backend, ws=ws), carry), None
 
     es, _ = jax.lax.scan(body, es, (bk, bp))
     store, lens, table, spilled, nruns, overflow = _engine_finish(
@@ -709,7 +710,6 @@ def _sharded_fn(
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed import groupby as gb_mod
-    from repro.distributed._compat import shard_map
 
     world = mesh.shape[axis]
 
@@ -740,11 +740,11 @@ def _sharded_fn(
         min=P(axis, None), max=P(axis, None),
     )
     n_stats = len(dataclasses.fields(DeviceSpillStats))
-    # the replication-check default is version-gated in _compat.shard_map
-    # (0.4.x check_rep has no while_loop rule); the stats out_specs are
-    # P() and truly replicated anyway (psum/pmax above).
-    inner = shard_map(
-        body, mesh=mesh, in_specs=(P(axis), P(axis, None)),
+    # the stats out_specs are P(): psum/pmax above make them replicated,
+    # which shard_map's varying-manual-axes check verifies.
+    inner = jax.shard_map(
+        body, mesh=mesh, check_vma=dispatch.checks_vma(backend),
+        in_specs=(P(axis), P(axis, None)),
         out_specs=(state_specs, DeviceSpillStats(*(P(),) * n_stats)),
     )
 
@@ -777,6 +777,30 @@ def _canon_inputs(keys, payload):
     return keys, payload
 
 
+def _host_pad_flat(keys: np.ndarray, payload, total: int):
+    """Host twin of :func:`_pad_flat`: EMPTY keys and zero payload rows
+    up to ``total`` rows."""
+    n = keys.shape[0]
+    if total == n:
+        return keys, payload
+    keys = np.concatenate(
+        [keys, np.full(total - n, empty_key(keys.dtype), keys.dtype)])
+    if payload is not None:
+        payload = np.concatenate(
+            [payload, np.zeros((total - n,) + payload.shape[1:], payload.dtype)])
+    return keys, payload
+
+
+def _place_sharded(keys, payload, mesh, axis: str):
+    """Row-shard host ``keys`` / ``payload`` over ``mesh[axis]``."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    with key_dtype_context(np.dtype(keys.dtype)):
+        return (jax.device_put(keys, NamedSharding(mesh, P(axis))),
+                jax.device_put(payload, NamedSharding(mesh, P(axis, None))))
+
+
 def _host_pad_for_geometry(keys, payload, policy: str, cfg: ExecConfig):
     """Pad HOST (NumPy) inputs to the pow2-bucketed batch geometry before
     the jit boundary, so the jit cache keys on geometry rather than N —
@@ -788,18 +812,8 @@ def _host_pad_for_geometry(keys, payload, policy: str, cfg: ExecConfig):
         return keys, payload
     chunk, _, _, _ = _engine_geometry(policy, cfg.memory_rows,
                                       cfg.batch_rows, cfg.page_rows)
-    n = keys.shape[0]
-    n_pad = _num_batches(n, chunk) * chunk
-    if n_pad == n:
-        return keys, payload
-    keys = np.concatenate(
-        [keys, np.full(n_pad - n, empty_key(keys.dtype), keys.dtype)]
-    )
-    if payload is not None:
-        payload = np.concatenate(
-            [payload, np.zeros((n_pad - n,) + payload.shape[1:], payload.dtype)]
-        )
-    return keys, payload
+    return _host_pad_flat(keys, payload,
+                          _num_batches(keys.shape[0], chunk) * chunk)
 
 
 def generate_runs_device(
@@ -920,6 +934,11 @@ def aggregate_device(
     pre = plan_pre_merge_levels(est, cfg, r_static)
     if payload is None:  # fixed (n, 0) payload: one in_spec tree
         payload = np.zeros((keys.shape[0], 0), np.float32)
+    if not isinstance(keys, jax.Array):
+        # host input goes straight to its shards: each device receives
+        # only its contiguous slice, never the whole input via device 0
+        keys, payload = _host_pad_flat(keys, payload, world * n_loc)
+        keys, payload = _place_sharded(keys, payload, mesh, axis)
     fn = _sharded_fn(
         mesh, axis, policy=policy,
         memory_rows=cfg.memory_rows, batch_rows=cfg.batch_rows,
@@ -1033,8 +1052,9 @@ def _absorb_chunk_body(es, bk, bp, *, policy, memory_rows, batch_rows,
 
     def body(carry, xs):
         ck, cp = xs
-        return _engine_step(carry, ck, cp, policy=policy, M=memory_rows,
-                            B=batch_rows, backend=backend, ws=widths), None
+        return match_vma(_engine_step(carry, ck, cp, policy=policy,
+                                      M=memory_rows, B=batch_rows,
+                                      backend=backend, ws=widths), carry), None
 
     loc, _ = jax.lax.scan(body, loc, (bk, bp))
     es = dataclasses.replace(
@@ -2295,10 +2315,10 @@ def _mesh_stream_fns(
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed import groupby as gb_mod
-    from repro.distributed._compat import shard_map
 
     kd = np.dtype(key_dtype_name)
     world = mesh.shape[axis]
+    vma = dispatch.checks_vma(backend)
     agg_spec = AggState(
         keys=P(axis), count=P(axis), sum=P(axis, None),
         min=P(axis, None), max=P(axis, None),
@@ -2323,8 +2343,8 @@ def _mesh_stream_fns(
             )
             return expand_engine_scalars(es)
 
-        return jax.jit(shard_map(
-            body, mesh=mesh, in_specs=(), out_specs=state_spec,
+        return jax.jit(jax.shard_map(
+            body, mesh=mesh, check_vma=vma, in_specs=(), out_specs=state_spec,
         ))
 
     @functools.lru_cache(maxsize=None)
@@ -2338,8 +2358,8 @@ def _mesh_stream_fns(
             return expand_engine_scalars(es)
 
         return jax.jit(
-            shard_map(
-                body, mesh=mesh,
+            jax.shard_map(
+                body, mesh=mesh, check_vma=vma,
                 in_specs=(state_spec, P(axis, None), P(axis, None, None)),
                 out_specs=state_spec,
             ),
@@ -2357,8 +2377,8 @@ def _mesh_stream_fns(
 
         # no donation: shapes change across the grow
         return jax.jit(
-            shard_map(body, mesh=mesh, in_specs=(state_spec,),
-                      out_specs=state_spec),
+            jax.shard_map(body, mesh=mesh, check_vma=vma,
+                          in_specs=(state_spec,), out_specs=state_spec),
         )
 
     @functools.lru_cache(maxsize=None)
@@ -2401,8 +2421,8 @@ def _mesh_stream_fns(
         # which is also what makes this program double as the per-shard
         # merge-on-read snapshot (the live state survives the call)
         return jax.jit(
-            shard_map(
-                body, mesh=mesh, in_specs=in_specs,
+            jax.shard_map(
+                body, mesh=mesh, check_vma=vma, in_specs=in_specs,
                 out_specs=(agg_spec, DeviceSpillStats(*(P(),) * n_stats)),
             ),
         )
@@ -2421,8 +2441,8 @@ def _mesh_stream_fns(
         in_specs = ((state_spec, P())
                     + ((P(axis),) if with_retired else ()))
         return jax.jit(
-            shard_map(
-                body, mesh=mesh, in_specs=in_specs,
+            jax.shard_map(
+                body, mesh=mesh, check_vma=vma, in_specs=in_specs,
                 out_specs=(state_spec, P(axis), P()),
             ),
             donate_argnums=(0,),

@@ -311,14 +311,17 @@ def _generate_runs_rs_body(keys, payload, cfg, *, backend, widths, width, cap,
     run_table = empty_state(cap, width, key_dtype=keys.dtype, widths=widths)
     next_table = empty_state(cap, width, key_dtype=keys.dtype, widths=widths)
     frontier = jnp.zeros((), keys.dtype)
-    open_chunks: list[AggState] = []  # evicted pieces of the open run
+    # evicted pieces of the open run, kept on the host: their lengths are
+    # data-dependent, and device slices would compile once per length
+    open_chunks: list[AggState] = []
     open_len = 0
 
     def close_run():
         nonlocal open_chunks, open_len
         if open_len == 0:
             return
-        state = jax.tree.map(lambda *xs: jnp.concatenate(xs, 0), *open_chunks)
+        state = jax.tree.map(lambda *xs: jnp.asarray(np.concatenate(xs, 0)),
+                             *open_chunks)
         runs.append(Run(state=state, length=open_len))
         stats.runs_generated += 1
         open_chunks, open_len = [], 0
@@ -339,7 +342,7 @@ def _generate_runs_rs_body(keys, payload, cfg, *, backend, widths, width, cap,
                 continue
             evicted, run_table, frontier, n_ev = _rs_evict(run_table, B, backend=backend)
             n_ev = int(n_ev)
-            trimmed = jax.tree.map(lambda x: x[:n_ev], evicted)
+            trimmed = jax.tree.map(lambda x: np.asarray(x)[:n_ev], evicted)
             open_chunks.append(trimmed)
             open_len += n_ev
             stats.rows_spilled_run_generation += n_ev
@@ -354,7 +357,8 @@ def _generate_runs_rs_body(keys, payload, cfg, *, backend, widths, width, cap,
     # trim, and evict-shift all preserve it), so no re-sort is needed.
     occ_r = int(run_table.occupancy())
     if occ_r > 0:
-        open_chunks.append(jax.tree.map(lambda x: x[:occ_r], run_table))
+        open_chunks.append(jax.tree.map(lambda x: np.asarray(x)[:occ_r],
+                                        run_table))
         open_len += occ_r
         stats.rows_spilled_run_generation += occ_r
     close_run()

@@ -41,8 +41,10 @@ import logging
 from collections.abc import Iterator
 from typing import Any, Mapping, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core import cost_model
 from repro.core import dispatch
@@ -447,6 +449,12 @@ class AggResult:
         return out
 
 
+def _replicate(x: jax.Array) -> jax.Array:
+    if not isinstance(x.sharding, NamedSharding):
+        return x
+    return jax.device_put(x, NamedSharding(x.sharding.mesh, PartitionSpec()))
+
+
 def _check_join_compat(left_by: KeySpec, right_by: KeySpec) -> None:
     """Joining two relations requires ONE shared packed key space: same
     key dtype and same column bit layout.  Anything else raises loudly —
@@ -545,8 +553,12 @@ class JoinResult:
             s for s in (self.left, self.right, self.products) if s is not None
         )
         if self.plan.get("mesh"):
-            # identical key vectors ⇒ identical compaction ⇒ alignment holds
-            states = tuple(mj_mod.compact_state(s) for s in states)
+            # identical key vectors ⇒ identical compaction ⇒ alignment
+            # holds.  The compaction moves rows across shards, so the
+            # key-range-sharded states are replicated over their mesh first.
+            states = tuple(
+                mj_mod.compact_state(jax.tree.map(_replicate, s))
+                for s in states)
         return states + (None,) * (3 - len(states))
 
     def relation(self) -> dict[str, np.ndarray]:
@@ -621,7 +633,6 @@ def _mesh_merge_join(a: AggState, b: AggState, mesh, mesh_axis, *,
     from repro.core.pipeline import resolve_mesh_axis
     from repro.core.types import ExchangeOverflowError
     from repro.distributed import groupby as gb_mod
-    from repro.distributed._compat import shard_map
 
     axis = resolve_mesh_axis(mesh, mesh_axis)
     world = int(mesh.shape[axis])
@@ -647,8 +658,10 @@ def _mesh_merge_join(a: AggState, b: AggState, mesh, mesh_axis, *,
                 quota_a=qa, quota_b=qb,
             )
 
-        return shard_map(body, mesh=mesh, in_specs=(spec, spec),
-                         out_specs=(spec, spec, P(), P(), P(), P()))
+        return jax.jit(jax.shard_map(
+            body, mesh=mesh, check_vma=dispatch.checks_vma(backend),
+            in_specs=(spec, spec),
+            out_specs=(spec, spec, P(), P(), P(), P())))
 
     left, right, rows_sent, send_dropped, dropped, max_fill = (
         sharded(q_a, q_b)(a, b))

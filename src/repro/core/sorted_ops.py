@@ -46,12 +46,16 @@ from repro.core.types import (
 # ---------------------------------------------------------------------------
 
 
+@functools.partial(jax.jit, static_argnames="backend", inline=True)
+def _sort_state(state: AggState, *, backend: str) -> AggState:
+    return take(state, dispatch.get_backend(backend).argsort(state.keys))
+
+
 def sort_state(state: AggState, *, backend: str = "auto") -> AggState:
     """Key-sort all rows of a state; EMPTY (=key dtype max) rows sink to
     the end."""
     with key_dtype_context(state):
-        perm = dispatch.get_backend(backend).argsort(state.keys)
-        return take(state, perm)
+        return _sort_state(state, backend=backend)
 
 
 # ---------------------------------------------------------------------------
@@ -68,7 +72,12 @@ def segmented_combine(state: AggState, *, backend: str = "auto") -> AggState:
     letting existing keys absorb the new rows.
     """
     with key_dtype_context(state):
-        return dispatch.get_backend(backend).segmented_combine(state)
+        return _segmented_combine(state, backend=backend)
+
+
+@functools.partial(jax.jit, static_argnames="backend", inline=True)
+def _segmented_combine(state: AggState, *, backend: str) -> AggState:
+    return dispatch.get_backend(backend).segmented_combine(state)
 
 
 def absorb(state: AggState, *, backend: str = "auto") -> AggState:
@@ -97,9 +106,17 @@ def merge_absorb(
     rows and the absorb drops to a single pair-combine.
     """
     with key_dtype_context(table):
-        return dispatch.get_backend(backend).merge_sorted(
-            table, incoming, assume_unique=assume_unique
-        )
+        return _merge_absorb(table, incoming, backend=backend,
+                             assume_unique=assume_unique)
+
+
+@functools.partial(jax.jit, static_argnames=("backend", "assume_unique"),
+                   inline=True)
+def _merge_absorb(table: AggState, incoming: AggState, *, backend: str,
+                  assume_unique: bool) -> AggState:
+    return dispatch.get_backend(backend).merge_sorted(
+        table, incoming, assume_unique=assume_unique
+    )
 
 
 def interleave(a: AggState, b: AggState, *, backend: str = "auto") -> AggState:
@@ -109,12 +126,16 @@ def interleave(a: AggState, b: AggState, *, backend: str = "auto") -> AggState:
     aggregation (the paper's Fig 2 top baseline) are trees of exactly
     this operation.  Backends without a fused kernel fall back to the
     XLA rank-gather interleave."""
+    with key_dtype_context(a):
+        return _interleave(a, b, backend=backend)
+
+
+@functools.partial(jax.jit, static_argnames="backend", inline=True)
+def _interleave(a: AggState, b: AggState, *, backend: str) -> AggState:
     from repro.core import ordered_index as oi
 
-    with key_dtype_context(a):
-        be = dispatch.get_backend(backend)
-        fn = be.interleave or oi.interleave_sorted
-        return fn(a, b)
+    fn = dispatch.get_backend(backend).interleave or oi.interleave_sorted
+    return fn(a, b)
 
 
 def _merge_tree(states: list[AggState], pair_fn) -> AggState:

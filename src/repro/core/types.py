@@ -89,15 +89,32 @@ def key_dtype_context(x):
 
     JAX canonicalizes 64-bit types away unless x64 is enabled; enabling it
     globally would change dtype semantics for the whole process (models,
-    optimizers, …).  This scopes ``jax.experimental.enable_x64`` to the
-    engine call operating on uint64 keys and is a no-op for uint32.
+    optimizers, …).  This scopes ``jax.enable_x64(True)`` to the engine
+    call operating on uint64 keys and is a no-op for uint32.
     Accepts an array, an AggState, or a dtype.
     """
     if _dtype_of(x) == np.uint64:
-        from jax.experimental import enable_x64
-
-        return enable_x64()
+        return jax.enable_x64(True)
     return contextlib.nullcontext()
+
+
+def match_vma(tree, ref):
+    """Cast every leaf of ``tree`` to vary over the manual mesh axes that
+    any leaf of ``ref`` varies over.
+
+    Inside ``shard_map`` a fresh constant (an initial scan carry, a
+    reset counter in one ``cond`` branch) is invariant across the mesh
+    axes while the values it meets there are per-shard, and the
+    varying-manual-axes check refuses the mismatch.  Outside
+    ``shard_map`` nothing varies and this is the identity."""
+    axes = frozenset().union(
+        *(jax.typeof(x).vma for x in jax.tree.leaves(ref)))
+
+    def cast(x):
+        missing = tuple(sorted(axes - jax.typeof(x).vma))
+        return jax.lax.pcast(x, missing, to="varying") if missing else x
+
+    return jax.tree.map(cast, tree) if axes else tree
 
 
 def as_key_array(keys) -> jax.Array:

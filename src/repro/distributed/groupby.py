@@ -45,8 +45,9 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import merge as merge_mod
 from repro.core import sorted_ops
-from repro.core.types import AggState, empty_key, max_key, rows_to_state
-from repro.distributed._compat import shard_map
+from repro.core.types import (
+    AggState, empty_key, match_vma, max_key, rows_to_state,
+)
 
 _log = logging.getLogger(__name__)
 
@@ -160,7 +161,8 @@ def strictify_cuts(cuts):
         return (nxt, jnp.bool_(True)), nxt
 
     (_, _), out = jax.lax.scan(
-        step, (jnp.zeros((), kd), jnp.bool_(False)), jnp.minimum(cuts, top)
+        step, (match_vma(jnp.zeros((), kd), cuts), jnp.bool_(False)),
+        jnp.minimum(cuts, top),
     )
     return out
 
@@ -430,8 +432,9 @@ def make_distributed_groupby(mesh, axis: str = "data", *, capacity: int,
             local_dropped | merge_dropped | recv_dropped
         )
 
+    @functools.cache
     def sharded(quota):
-        return shard_map(
+        return jax.jit(jax.shard_map(
             functools.partial(local_fn, quota), mesh=mesh,
             in_specs=(P(axis), P(axis, None)),
             out_specs=(
@@ -442,7 +445,7 @@ def make_distributed_groupby(mesh, axis: str = "data", *, capacity: int,
                 P(),
                 P(),
             ),
-        )
+        ))
 
     q0 = (default_exchange_quota(capacity, world) if exchange_quota is None
           else exchange_quota)

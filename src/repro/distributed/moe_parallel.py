@@ -34,7 +34,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.models.config import ModelConfig
 
-from repro.distributed._compat import shard_map
+from jax import shard_map
 
 
 def _local_sorted_dispatch(x_flat, eidx, w, e: int, cap: int):

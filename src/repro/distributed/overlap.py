@@ -14,7 +14,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.distributed._compat import shard_map
+from jax import shard_map
 
 
 def ring_allgather_matmul(mesh, axis: str = "model"):

@@ -20,7 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.distributed._compat import shard_map
+from jax import shard_map
 
 
 def bubble_fraction(stages: int, microbatches: int) -> float:

@@ -30,15 +30,18 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.segmented_reduce import _lex_leq
+from repro.kernels.segmented_reduce import _lex_leq, pallas_call
 
 
-def _cex(key_lanes, move_lanes, j: int, direction):
+def _cex(key_lanes, move_lanes, j: int, dir_shift: int | None):
     """One compare-exchange stage at stride j.
 
-    key_lanes / move_lanes: tuples of (1, N) arrays; direction: (1, N)
-    bool, True = ascending block.  Keys compare lexicographically across
-    lanes; move lanes travel with their row.
+    key_lanes / move_lanes: tuples of (1, N) arrays.  A row's block sorts
+    descending iff bit ``dir_shift`` of its index is set (``None``: the
+    whole tile ascends).  Keys compare lexicographically across lanes;
+    move lanes travel with their row.  Masks come from int32 index bits
+    and are combined with and/or/not only: Mosaic cannot select between
+    boolean vectors.
     """
     idx = jax.lax.broadcasted_iota(jnp.int32, key_lanes[0].shape, 1)
     upper = (idx & j) != 0  # bit_j set → partner is i - j
@@ -48,11 +51,14 @@ def _cex(key_lanes, move_lanes, j: int, direction):
         return jnp.where(upper, jnp.roll(x, j, axis=-1), jnp.roll(x, -j, axis=-1))
 
     part_keys = tuple(partner(k) for k in key_lanes)
-    # ascending: lane with bit clear keeps min, bit set keeps max
-    keep_min = jnp.where(direction, ~upper, upper)
-    take_self = jnp.where(
-        keep_min, _lex_leq(key_lanes, part_keys), _lex_leq(part_keys, key_lanes)
-    )
+    # ascending block: the row with bit_j clear keeps the min; a
+    # descending block flips that, so keep the min iff bit_j == bit_dir
+    flip = idx >> (j.bit_length() - 1)
+    if dir_shift is not None:
+        flip = flip ^ (idx >> dir_shift)
+    keep_min = (flip & 1) == 0
+    take_self = (keep_min & _lex_leq(key_lanes, part_keys)) | (
+        ~keep_min & _lex_leq(part_keys, key_lanes))
     new_keys = tuple(jnp.where(take_self, k, p) for k, p in zip(key_lanes, part_keys))
     new_move = tuple(jnp.where(take_self, m, partner(m)) for m in move_lanes)
     return new_keys, new_move
@@ -61,14 +67,13 @@ def _cex(key_lanes, move_lanes, j: int, direction):
 def _bitonic_body(key_lanes, move_lanes):
     n = key_lanes[0].shape[-1]
     assert n & (n - 1) == 0, "tile length must be a power of two"
-    idx = jax.lax.broadcasted_iota(jnp.int32, key_lanes[0].shape, 1)
     k = 2
     while k <= n:
         # block of size k sorts ascending iff bit_k(i) clear (global ascending)
-        direction = (idx & k) == 0 if k < n else jnp.ones_like(idx, dtype=bool)
+        dir_shift = k.bit_length() - 1 if k < n else None
         j = k // 2
         while j >= 1:
-            key_lanes, move_lanes = _cex(key_lanes, move_lanes, j, direction)
+            key_lanes, move_lanes = _cex(key_lanes, move_lanes, j, dir_shift)
             j //= 2
         k *= 2
     return key_lanes, move_lanes
@@ -100,7 +105,7 @@ def bitonic_sort_multi(key_lanes, move_lanes=(), *, interpret: bool = True):
     t, n = key_lanes[0].shape
     spec = pl.BlockSpec((1, n), lambda i: (i, 0))
     all_in = key_lanes + move_lanes
-    out = pl.pallas_call(
+    out = pallas_call(
         _make_kernel(len(key_lanes), len(move_lanes)),
         out_shape=tuple(jax.ShapeDtypeStruct((t, n), x.dtype) for x in all_in),
         grid=(t,),

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.types import EMPTY
-from repro.kernels.segmented_reduce import _segmented_scan
+from repro.kernels.segmented_reduce import _segmented_scan, pallas_call
 
 
 def _bitonic_merge(keys, cols):
@@ -82,7 +82,7 @@ def merge_absorb_tiles(ka, ca, sa, mna, mxa, kb, cb, sb, mnb, mxb, *,
     sv = pl.BlockSpec((1, v, n), lambda i: (i, 0, 0))
     o1 = pl.BlockSpec((1, 2 * n), lambda i: (i, 0))
     ov = pl.BlockSpec((1, v, 2 * n), lambda i: (i, 0, 0))
-    return pl.pallas_call(
+    *out, tails = pallas_call(
         _kernel,
         out_shape=(
             jax.ShapeDtypeStruct((t, 2 * n), ka.dtype),
@@ -90,10 +90,11 @@ def merge_absorb_tiles(ka, ca, sa, mna, mxa, kb, cb, sb, mnb, mxb, *,
             jax.ShapeDtypeStruct((t, v, 2 * n), sa.dtype),
             jax.ShapeDtypeStruct((t, v, 2 * n), mna.dtype),
             jax.ShapeDtypeStruct((t, v, 2 * n), mxa.dtype),
-            jax.ShapeDtypeStruct((t, 2 * n), jnp.bool_),
+            jax.ShapeDtypeStruct((t, 2 * n), jnp.int32),
         ),
         grid=(t,),
         in_specs=[s1, s1, sv, sv, sv, s1, s1, sv, sv, sv],
         out_specs=(o1, o1, ov, ov, ov, o1),
         interpret=interpret,
     )(ka, ca, sa, mna, mxa, kb, cb, sb, mnb, mxb)
+    return (*out, tails != 0)

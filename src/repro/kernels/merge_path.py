@@ -1,17 +1,18 @@
-"""Merge-path kernel: fused linear merge + absorb of two sorted tiles.
+"""Merge kernel: fused linear merge + absorb of two sorted tiles.
 
 This is the Pallas twin of :mod:`repro.core.ordered_index`'s rank-scatter
 merge, and the replacement for the bitonic-merge kernel in
 :mod:`repro.kernels.merge_aggregate` on the engine's hot path.
 
-Merge Path (Green, McColl & Bader): output lane ``k`` of the merged
-sequence lies on the ``k``-th anti-diagonal of the |A|×|B| merge grid;
-the crossing point ``(i, k-i)`` — "``i`` rows of A and ``k-i`` rows of B
-precede output ``k``" — is found by a per-lane binary search over the
-diagonal.  All ``|A|+|B|`` lanes search independently, so the whole merge
-is ⌈log₂N⌉ data-parallel probe rounds followed by ONE gather, instead of
-the bitonic merge's log₂(2N) full-width compare-exchange sweeps over keys
-*and every payload column*.  The duplicate absorb (flag-based segmented
+Co-ranking (the idea behind Merge Path, Green, McColl & Bader): in the
+stable merge of sorted A and B, row ``i`` of A lands at output position
+``i + #{j : B[j] < A[i]}`` and row ``j`` of B at ``j + #{i : A[i] <= B[j]}``
+(A wins ties), so the positions form a permutation of the output.  The
+kernel counts both ranks with one broadcast comparison of the two tiles,
+then places every row with a one-hot select — the TPU's vector unit has
+no gather across vector registers, so no data-dependent indexing is used
+at all.  That costs O(|A|·|B|) compares per tile, which bounds the tile
+(``ops.MAX_TILE_ROWS``).  The duplicate absorb (flag-based segmented
 scan, shared with :mod:`repro.kernels.segmented_reduce`) runs fused in
 the same VMEM residency, so one page absorb costs one HBM round trip.
 
@@ -20,81 +21,88 @@ Inputs need only be **sorted** — duplicates within either input are fine
 Keys arrive as one or two uint32 **lanes**: 32-bit keys are one lane,
 64-bit keys a (hi, lo) pair compared lexicographically per lane — the
 TPU path needs no native 64-bit ops.  EMPTY (= all lanes 0xFFFF_FFFF)
-padding ranks to the tail like any other key.
+padding ranks to the tail like any other key.  Each tile arrives both as
+a row (1, N) and as a column (N, 1): comparing a column with a row
+broadcasts to the (N, M) rank matrix, and summing it along its lanes or
+sublanes gives a rank per row in either layout.
 """
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.segmented_reduce import _lanes_eq, _lanes_empty, _lex_leq, _segmented_scan
+from repro.kernels.segmented_reduce import (
+    _lanes_empty,
+    _lanes_eq,
+    _lex_leq,
+    _segmented_scan,
+    pallas_call,
+)
 
 
-def _merge_path_split(ka_lanes, kb_lanes):
-    """Per-lane diagonal binary search.
+def _lt(a_lanes, b_lanes):
+    """a < b on multi-lane keys (hi lane first)."""
+    return ~_lex_leq(b_lanes, a_lanes)
 
-    ka_lanes / kb_lanes: tuples of (1, N) / (1, M) uint32 key lanes (hi
-    lane first), each lexicographically sorted ascending.  Returns
-    ``(ia, ib, take_a)`` of shape (1, N+M): lane ``k`` of the merged
-    output reads ``A[ia[k]]`` when ``take_a[k]`` else ``B[ib[k]]``
-    (stable: A wins ties).
-    """
-    n, m = ka_lanes[0].shape[-1], kb_lanes[0].shape[-1]
-    a_lanes = [k[0] for k in ka_lanes]
-    b_lanes = [k[0] for k in kb_lanes]
-    k = jax.lax.broadcasted_iota(jnp.int32, (1, n + m), 1)
-    lo = jnp.maximum(0, k - m)  # feasible: all of B already consumed
-    hi = jnp.minimum(k, n)
-    # predicate g(i) = "taking i rows of A before lane k is feasible",
-    # i.e. A[i-1] <= B[k-i]; monotone decreasing in i, so binary search
-    # for the largest feasible i.  Boundary clauses make the comparison
-    # vacuous when either side is exhausted.
-    for _ in range(int(math.ceil(math.log2(max(n, m) + 1))) + 1):
-        mid = (lo + hi + 1) >> 1
-        a_prev = [jnp.take(a, jnp.clip(mid - 1, 0, n - 1)) for a in a_lanes]
-        b_next = [jnp.take(b, jnp.clip(k - mid, 0, m - 1)) for b in b_lanes]
-        ok = (mid <= 0) | (k - mid >= m) | _lex_leq(a_prev, b_next)
-        lo = jnp.where(ok, mid, lo)
-        hi = jnp.where(ok, hi, mid - 1)
-    ia = lo
-    ib = k - lo
-    a_key = [jnp.take(a, jnp.clip(ia, 0, n - 1)) for a in a_lanes]
-    b_key = [jnp.take(b, jnp.clip(ib, 0, m - 1)) for b in b_lanes]
-    take_a = (ia < n) & ((ib >= m) | _lex_leq(a_key, b_key))
-    return jnp.clip(ia, 0, n - 1), jnp.clip(ib, 0, m - 1), take_a
+
+def _count(mask, axis: int):
+    return jnp.sum(mask.astype(jnp.int32), axis=axis, keepdims=True,
+                   dtype=jnp.int32)
+
+
+def _place(onehot_a, onehot_b, xa, xb):
+    """Output row (1, K) whose lane k holds the A row (column ``xa``,
+    (N, 1)) or B row (``xb``, (M, 1)) the one-hot masks put there."""
+    as_int = xa.dtype == jnp.uint32
+    if as_int:  # sum in int32: exactly one term per lane is non-zero
+        xa = jax.lax.bitcast_convert_type(xa, jnp.int32)
+        xb = jax.lax.bitcast_convert_type(xb, jnp.int32)
+    zero = jnp.zeros((), xa.dtype)
+
+    def pick(onehot, x):  # dtype pinned: x64 would widen an int32 sum
+        return jnp.sum(jnp.where(onehot, x, zero), axis=0, keepdims=True,
+                       dtype=x.dtype)
+
+    row = pick(onehot_a, xa) + pick(onehot_b, xb)
+    return jax.lax.bitcast_convert_type(row, jnp.uint32) if as_int else row
 
 
 def _make_kernel(nlanes: int):
     def _kernel(*refs):
-        ka_refs = refs[:nlanes]
-        ca_ref, sa_ref, mna_ref, mxa_ref = refs[nlanes : nlanes + 4]
-        kb_refs = refs[nlanes + 4 : 2 * nlanes + 4]
-        cb_ref, sb_ref, mnb_ref, mxb_ref = refs[2 * nlanes + 4 : 2 * nlanes + 8]
-        outs = refs[2 * nlanes + 8 :]
+        ka_row = tuple(r[...] for r in refs[:nlanes])
+        kb_row = tuple(r[...] for r in refs[nlanes:2 * nlanes])
+        ka_col = tuple(r[...] for r in refs[2 * nlanes:3 * nlanes])
+        kb_col = tuple(r[...] for r in refs[3 * nlanes:4 * nlanes])
+        ca_ref, sa_ref, mna_ref, mxa_ref = refs[4 * nlanes:4 * nlanes + 4]
+        cb_ref, sb_ref, mnb_ref, mxb_ref = refs[4 * nlanes + 4:4 * nlanes + 8]
+        outs = refs[4 * nlanes + 8:]
         ok_refs = outs[:nlanes]
         oc_ref, os_ref, omn_ref, omx_ref, ot_ref = outs[nlanes:]
 
-        ka = tuple(k[...] for k in ka_refs)
-        kb = tuple(k[...] for k in kb_refs)
-        ia, ib, take_a = _merge_path_split(ka, kb)
+        n, m = ka_col[0].shape[0], kb_col[0].shape[0]
+        k_out = jax.lax.broadcasted_iota(jnp.int32, (1, n + m), 1)
+        pos_a = (jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
+                 + _count(_lt(kb_row, ka_col), 1))
+        pos_b = (jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0)
+                 + _count(_lex_leq(ka_row, kb_col), 1))
+        onehot_a, onehot_b = pos_a == k_out, pos_b == k_out
 
-        def sel1(xa, xb):  # (1,N)/(1,M) → (1,N+M)
-            return jnp.where(take_a, jnp.take(xa[0], ia), jnp.take(xb[0], ib))
+        def place(xa, xb):
+            return _place(onehot_a, onehot_b, xa, xb)
 
-        def selv(xa, xb):  # (V,N)/(V,M) → (V,N+M); take_a broadcasts over V
-            ga = jnp.take(xa, ia[0], axis=-1)
-            gb = jnp.take(xb, ib[0], axis=-1)
-            return jnp.where(take_a, ga, gb)
+        def planes(xa, xb):  # (N, V) / (M, V) columns → (V, N+M) rows
+            return jnp.concatenate(
+                [place(xa[:, v:v + 1], xb[:, v:v + 1])
+                 for v in range(xa.shape[1])], axis=0)
 
-        keys = tuple(sel1(a, b) for a, b in zip(ka, kb))
-        cnt = sel1(ca_ref[...], cb_ref[...])
-        ssum = selv(sa_ref[0], sb_ref[0])
-        smin = selv(mna_ref[0], mnb_ref[0])
-        smax = selv(mxa_ref[0], mxb_ref[0])
+        keys = tuple(place(a, b) for a, b in zip(ka_col, kb_col))
+        cnt = place(ca_ref[...], cb_ref[...])
+        ssum = planes(sa_ref[0], sb_ref[0])
+        smin = planes(mna_ref[0], mnb_ref[0])
+        smax = planes(mxa_ref[0], mxb_ref[0])
         # absorb duplicates (segmented scan) while everything is VMEM-resident
         cnt, ssum, smin, smax, tails = _segmented_scan(keys, cnt, ssum, smin, smax)
         for o, kk in zip(ok_refs, keys):
@@ -108,36 +116,51 @@ def _make_kernel(nlanes: int):
     return _kernel
 
 
+def _lanes(k):
+    return tuple(k) if isinstance(k, (tuple, list)) else (k,)
+
+
+def _col(x):
+    """(T, N) tile set → (T·N, 1): each tile as a column."""
+    return x.reshape(-1, 1)
+
+
+def _cols(x):
+    """(T, V, N) value tiles → (T, N, V): each plane as a column."""
+    return jnp.swapaxes(x, 1, 2)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def merge_path_tiles(ka, ca, sa, mna, mxa, kb, cb, sb, mnb, mxb, *,
                      interpret: bool = True):
     """Merge two sorted tile sets — (T,N)+(T,M) key lane(s), (T,V?,·)
-    payloads — into (T,N+M) merged + scanned aggregates + tail mask.
-    ``ka``/``kb`` are (T,N) arrays (one lane) or tuples of (T,N) uint32
-    lanes (hi first) for 64-bit keys.  Unlike the bitonic kernel, N and M
-    need not match (compaction by the caller, see ops.py), and the sum /
-    min / max planes may have different widths."""
-    ka_lanes = tuple(ka) if isinstance(ka, (tuple, list)) else (ka,)
-    kb_lanes = tuple(kb) if isinstance(kb, (tuple, list)) else (kb,)
+    payloads — into (T,N+M) merged + scanned aggregates + boolean tail
+    mask.  ``ka``/``kb`` are (T,N) arrays (one lane) or tuples of (T,N)
+    uint32 lanes (hi first) for 64-bit keys.  N and M need not match
+    (compaction by the caller, see ops.py), and the sum / min / max
+    planes may have different widths."""
+    ka_lanes, kb_lanes = _lanes(ka), _lanes(kb)
     assert len(ka_lanes) == len(kb_lanes)
     nlanes = len(ka_lanes)
     t, n = ka_lanes[0].shape
     m = kb_lanes[0].shape[-1]
     k_out = n + m
-    sa_spec = pl.BlockSpec((1, n), lambda i: (i, 0))
-    sb_spec = pl.BlockSpec((1, m), lambda i: (i, 0))
 
-    def vspec(x):
-        v = x.shape[1]
-        w = x.shape[-1]
-        return pl.BlockSpec((1, v, w), lambda i: (i, 0, 0))
+    def row(w):
+        return pl.BlockSpec((1, w), lambda i: (i, 0))
 
-    o1 = pl.BlockSpec((1, k_out), lambda i: (i, 0))
+    def col(w):
+        return pl.BlockSpec((w, 1), lambda i: (i, 0))
 
-    def ovspec(v):
+    def vcol(x):
+        return pl.BlockSpec((1,) + x.shape[1:], lambda i: (i, 0, 0))
+
+    def ov(v):
         return pl.BlockSpec((1, v, k_out), lambda i: (i, 0, 0))
 
-    return pl.pallas_call(
+    a_vals = (_col(ca), _cols(sa), _cols(mna), _cols(mxa))
+    b_vals = (_col(cb), _cols(sb), _cols(mnb), _cols(mxb))
+    *out, tails = pallas_call(
         _make_kernel(nlanes),
         out_shape=tuple(
             jax.ShapeDtypeStruct((t, k_out), k.dtype) for k in ka_lanes
@@ -146,45 +169,35 @@ def merge_path_tiles(ka, ca, sa, mna, mxa, kb, cb, sb, mnb, mxb, *,
             jax.ShapeDtypeStruct((t, sa.shape[1], k_out), sa.dtype),
             jax.ShapeDtypeStruct((t, mna.shape[1], k_out), mna.dtype),
             jax.ShapeDtypeStruct((t, mxa.shape[1], k_out), mxa.dtype),
-            jax.ShapeDtypeStruct((t, k_out), jnp.bool_),
+            jax.ShapeDtypeStruct((t, k_out), jnp.int32),
         ),
         grid=(t,),
-        in_specs=[sa_spec] * nlanes
-        + [sa_spec, vspec(sa), vspec(mna), vspec(mxa)]
-        + [sb_spec] * nlanes
-        + [sb_spec, vspec(sb), vspec(mnb), vspec(mxb)],
-        out_specs=tuple([o1] * nlanes) + (
-            o1, ovspec(sa.shape[1]), ovspec(mna.shape[1]), ovspec(mxa.shape[1]), o1,
+        in_specs=[row(n)] * nlanes + [row(m)] * nlanes
+        + [col(n)] * nlanes + [col(m)] * nlanes
+        + [col(n)] + [vcol(x) for x in a_vals[1:]]
+        + [col(m)] + [vcol(x) for x in b_vals[1:]],
+        out_specs=tuple([row(k_out)] * nlanes) + (
+            row(k_out), ov(sa.shape[1]), ov(mna.shape[1]), ov(mxa.shape[1]),
+            row(k_out),
         ),
         interpret=interpret,
-    )(*ka_lanes, ca, sa, mna, mxa, *kb_lanes, cb, sb, mnb, mxb)
+    )(*ka_lanes, *kb_lanes, *map(_col, ka_lanes), *map(_col, kb_lanes),
+      *a_vals, *b_vals)
+    return (*out, tails != 0)
 
 
-def _make_probe_kernel(nlanes: int, m: int):
+def _make_probe_kernel(nlanes: int):
     def _kernel(*refs):
-        ka_refs = refs[:nlanes]
-        kb_refs = refs[nlanes : 2 * nlanes]
-        pos_ref, hit_ref = refs[2 * nlanes :]
-
-        a_lanes = [k[...][0] for k in ka_refs]
-        b_lanes = [k[...][0] for k in kb_refs]
-        n = a_lanes[0].shape[-1]
-        # lower_bound per output lane: smallest j with A[i] <= B[j]
-        # (monotone in j since B is sorted), by the same fixed-round
-        # binary search the merge split uses — all n lanes in parallel.
-        lo = jnp.zeros((n,), jnp.int32)
-        hi = jnp.full((n,), m, jnp.int32)
-        for _ in range(int(math.ceil(math.log2(m + 1))) + 1):
-            mid = (lo + hi) >> 1
-            b_mid = [jnp.take(b, jnp.clip(mid, 0, m - 1)) for b in b_lanes]
-            leq = _lex_leq(a_lanes, b_mid)
-            hi = jnp.where(leq, mid, hi)
-            lo = jnp.where(leq, lo, mid + 1)
-        pos = jnp.clip(lo, 0, m - 1)
-        probed = [jnp.take(b, pos) for b in b_lanes]
-        hit = _lanes_eq(a_lanes, probed) & ~_lanes_empty(a_lanes)
-        pos_ref[...] = pos[None]
-        hit_ref[...] = hit[None]
+        ka_row = tuple(r[...] for r in refs[:nlanes])
+        kb_col = tuple(r[...] for r in refs[nlanes:2 * nlanes])
+        pos_ref, hit_ref = refs[2 * nlanes:]
+        m = kb_col[0].shape[0]
+        # lower_bound of A[i] in sorted B is #{j : B[j] < A[i]}; A[i] is
+        # in B iff some B[j] equals it
+        pos = jnp.minimum(_count(_lt(kb_col, ka_row), 0), m - 1)
+        hit = (_count(_lanes_eq(kb_col, ka_row), 0) > 0) & ~_lanes_empty(ka_row)
+        pos_ref[...] = pos
+        hit_ref[...] = hit.astype(jnp.int32)
 
     return _kernel
 
@@ -195,25 +208,25 @@ def merge_path_probe_tiles(ka, kb, *, interpret: bool = True):
     ``ka`` — (T,N) uint32 array or tuple of lanes (hi first) — against
     sorted tile set ``kb`` (T,M).  Returns ``(pos, hit)`` of shape (T,N):
     ``kb[pos[i]] == ka[i]`` where ``hit`` (EMPTY keys never hit).  The
-    per-lane binary search is the probe half of the merge-path diagonal
-    split; no sort and no scatter, O(log M) rounds in one VMEM residency.
+    rank is the co-rank count of the merge kernel; no sort, no scatter,
+    no gather, one VMEM residency.
     """
-    ka_lanes = tuple(ka) if isinstance(ka, (tuple, list)) else (ka,)
-    kb_lanes = tuple(kb) if isinstance(kb, (tuple, list)) else (kb,)
+    ka_lanes, kb_lanes = _lanes(ka), _lanes(kb)
     assert len(ka_lanes) == len(kb_lanes)
     nlanes = len(ka_lanes)
     t, n = ka_lanes[0].shape
     m = kb_lanes[0].shape[-1]
     a_spec = pl.BlockSpec((1, n), lambda i: (i, 0))
-    b_spec = pl.BlockSpec((1, m), lambda i: (i, 0))
-    return pl.pallas_call(
-        _make_probe_kernel(nlanes, m),
+    b_spec = pl.BlockSpec((m, 1), lambda i: (i, 0))
+    pos, hit = pallas_call(
+        _make_probe_kernel(nlanes),
         out_shape=(
             jax.ShapeDtypeStruct((t, n), jnp.int32),
-            jax.ShapeDtypeStruct((t, n), jnp.bool_),
+            jax.ShapeDtypeStruct((t, n), jnp.int32),
         ),
         grid=(t,),
         in_specs=[a_spec] * nlanes + [b_spec] * nlanes,
         out_specs=(a_spec, a_spec),
         interpret=interpret,
-    )(*ka_lanes, *kb_lanes)
+    )(*ka_lanes, *map(_col, kb_lanes))
+    return pos, hit != 0

@@ -3,8 +3,13 @@
 These handle the impedance between user shapes and kernel tiles: padding
 to powers of two / MXU multiples, EMPTY-key padding, AggState struct ↔
 (T,N)/(T,V,N) tile layout, and the XLA-side compaction scatter that
-follows the in-kernel segmented scans.  ``interpret=True`` everywhere on
-CPU (Mosaic is TPU-only); the flag flips off on real hardware.
+follows the in-kernel segmented scans.  Interpret mode is decided at
+trace time by :func:`repro.core.dispatch.should_interpret`: everywhere
+off-TPU (Mosaic is TPU-only), never on a TPU.
+
+Each kernel holds its whole input in one VMEM tile.  On a TPU an input
+larger than :data:`MAX_TILE_ROWS` raises :class:`TileTooLarge`; it never
+falls back to the interpreter or to another backend.
 
 Key-width handling: kernels only ever see uint32 lanes.  A uint64 key
 vector is split here into a (hi, lo) pair of uint32 lanes — compared
@@ -35,11 +40,29 @@ from repro.kernels import merge_aggregate as _ma
 from repro.kernels import merge_path as _mp
 from repro.kernels import segmented_reduce as _sr
 
-# Centralized in repro.core.dispatch: interpret everywhere except on real
-# TPU (override with REPRO_PALLAS_INTERPRET=0/1).
-INTERPRET = _dispatch.should_interpret()
-
 _LO32 = 0xFFFFFFFF
+
+# Largest padded row count one kernel call holds in a single VMEM tile on
+# a TPU, per kernel family (compile-checked for v5e at these sizes).
+MAX_TILE_ROWS = {"sort": 4096, "scan": 16384, "merge": 1024, "probe": 2048}
+
+
+class TileTooLarge(ValueError):
+    """A Pallas kernel's input exceeds its single-tile VMEM limit."""
+
+
+def _interpret(kind: str, rows: int) -> bool:
+    """Interpret mode for one kernel call of ``rows`` padded rows; on a
+    TPU, refuse a tile over the kernel's VMEM limit."""
+    if _dispatch.should_interpret():
+        return True
+    if rows > MAX_TILE_ROWS[kind]:
+        raise TileTooLarge(
+            f"pallas {kind} kernel: {rows} rows exceed the single-tile limit "
+            f"of {MAX_TILE_ROWS[kind]} rows on TPU (the kernels are not "
+            "tiled across a grid yet); use backend='xla'"
+        )
+    return False
 
 
 def _next_pow2(n: int) -> int:
@@ -71,7 +94,8 @@ def sort_keys(keys: jax.Array) -> jax.Array:
         jnp.full((1, m), EMPTY, jnp.uint32).at[0, :n].set(lane)
         for lane in _key_lanes(keys)
     )
-    sorted_lanes, _ = _bs.bitonic_sort_multi(lanes, (), interpret=INTERPRET)
+    sorted_lanes, _ = _bs.bitonic_sort_multi(
+        lanes, (), interpret=_interpret("sort", m))
     return _lanes_to_keys(tuple(l[0, :n] for l in sorted_lanes), keys.dtype)
 
 
@@ -94,7 +118,7 @@ def argsort_keys(keys: jax.Array) -> jax.Array:
     )
     idx_lane = jnp.arange(m, dtype=jnp.uint32)[None, :]
     sorted_lanes, _ = _bs.bitonic_sort_multi(
-        lanes + (idx_lane,), (), interpret=INTERPRET
+        lanes + (idx_lane,), (), interpret=_interpret("sort", m)
     )
     perm = sorted_lanes[-1][0]
     return perm[:n].astype(jnp.int32)
@@ -165,7 +189,7 @@ def segmented_combine(state: AggState) -> AggState:
     state = _pad_state(state, n)
     key_lanes, cnt, ssum, smin, smax = _state_to_tiles(state, n)
     c2, s2, mn2, mx2, tails = _sr.segmented_scan_tiles(
-        key_lanes, cnt, ssum, smin, smax, interpret=INTERPRET
+        key_lanes, cnt, ssum, smin, smax, interpret=_interpret("scan", n)
     )
     out = _compact(state.keys, c2, s2, mn2, mx2, tails, state.widths)
     return jax.tree.map(lambda x: x[:n0], out)
@@ -188,7 +212,8 @@ def merge_absorb_sorted(a: AggState, b: AggState, *, assume_unique: bool = False
     ka, ca, sa, mna, mxa = _state_to_tiles(a, na)
     kb, cb, sb, mnb, mxb = _state_to_tiles(b, nb)
     out_tiles = _mp.merge_path_tiles(
-        ka, ca, sa, mna, mxa, kb, cb, sb, mnb, mxb, interpret=INTERPRET
+        ka, ca, sa, mna, mxa, kb, cb, sb, mnb, mxb,
+        interpret=_interpret("merge", na + nb),
     )
     nlanes = len(ka)
     merged_lanes = tuple(t[0] for t in out_tiles[:nlanes])
@@ -210,7 +235,8 @@ def merge_absorb_sorted_bitonic(a: AggState, b: AggState) -> AggState:
     (ka,), ca, sa, mna, mxa = _state_to_tiles(a, n)
     (kb,), cb, sb, mnb, mxb = _state_to_tiles(b, n)
     k2, c2, s2, mn2, mx2, tails = _ma.merge_absorb_tiles(
-        ka, ca, sa, mna, mxa, kb, cb, sb, mnb, mxb, interpret=INTERPRET
+        ka, ca, sa, mna, mxa, kb, cb, sb, mnb, mxb,
+        interpret=_dispatch.should_interpret(),
     )
     out = _compact(k2[0], c2, s2, mn2, mx2, tails, a.widths)
     return jax.tree.map(lambda x: x[: min(cap_out, 2 * n)], out)
@@ -233,7 +259,8 @@ def join_probe(a_keys: jax.Array, b_keys: jax.Array) -> tuple[jax.Array, jax.Arr
         jnp.full((1, m), EMPTY, jnp.uint32).at[0, :m0].set(lane)
         for lane in _key_lanes(b_keys)
     )
-    pos, hit = _mp.merge_path_probe_tiles(ka, kb, interpret=INTERPRET)
+    pos, hit = _mp.merge_path_probe_tiles(
+        ka, kb, interpret=_interpret("probe", max(n, m)))
     return jnp.clip(pos[0, :n0], 0, max(m0 - 1, 0)), hit[0, :n0]
 
 
@@ -246,5 +273,5 @@ def _pad_state(state: AggState, n: int) -> AggState:
 def moe_grouped_matmul(x, w, *, capacity, block_m=128, block_n=128, block_k=128):
     return _gm.grouped_matmul(
         x, w, capacity=capacity, block_m=block_m, block_n=block_n,
-        block_k=block_k, interpret=INTERPRET,
+        block_k=block_k, interpret=_dispatch.should_interpret(),
     )

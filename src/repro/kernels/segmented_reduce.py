@@ -32,7 +32,28 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.types import EMPTY
+from repro.core.types import EMPTY, match_vma
+
+
+def pallas_call(kernel, *, out_shape, **kw):
+    """``pl.pallas_call`` for the 32-bit kernels, callable from a uint64
+    engine and per shard.  Under the scoped x64 of 64-bit keys the
+    kernel (its grid index maps included) would trace with 64-bit
+    integers, which Mosaic cannot lower, so it traces with x64 off; its
+    operands are 32-bit lanes either way.  Inside a ``shard_map`` whose
+    varying-manual-axes check is on, the operands are cast to vary over
+    the same mesh axes and the outputs are declared to vary over them."""
+
+    def call(*args):
+        args = match_vma(args, args)
+        vma = frozenset().union(*(jax.typeof(a).vma for a in args))
+        shapes = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, vma=vma),
+            out_shape)
+        with jax.enable_x64(False):
+            return pl.pallas_call(kernel, out_shape=shapes, **kw)(*args)
+
+    return call
 
 
 def _lex_leq(a_lanes, b_lanes):
@@ -61,8 +82,9 @@ def _lanes_empty(lanes):
 
 def _segmented_scan(keys_lanes, cnt, ssum, smin, smax):
     """keys_lanes: tuple of (1,N) uint32 lanes (hi→lo); cnt (1,N);
-    ssum/smin/smax (V?,N).  Returns scanned values and the tail mask (last
-    row of each segment)."""
+    ssum/smin/smax (V?,N).  Returns scanned values and the int32 tail
+    flags (1 on the last row of each segment).  Segment flags travel as
+    int32: Mosaic can neither roll nor store boolean vectors."""
     if not isinstance(keys_lanes, (tuple, list)):
         keys_lanes = (keys_lanes,)
     k0 = keys_lanes[0]
@@ -71,12 +93,12 @@ def _segmented_scan(keys_lanes, cnt, ssum, smin, smax):
     valid = ~_lanes_empty(keys_lanes)
     prev = [jnp.roll(k, 1, axis=-1) for k in keys_lanes]
     heads = ~_lanes_eq(keys_lanes, prev) | (idx == 0)
-    f = heads | ~valid
+    f = (heads | ~valid).astype(jnp.int32)
     d = 1
     while d < n:
         fd = jnp.roll(f, d, axis=-1)
         edge = idx < d
-        can_add = (~f) & (~edge)
+        can_add = (f == 0) & ~edge
         cd = jnp.roll(cnt, d, axis=-1)
         cnt = jnp.where(can_add, cnt + cd, cnt)
         # value columns broadcast the (1,N) mask over their V rows
@@ -86,11 +108,11 @@ def _segmented_scan(keys_lanes, cnt, ssum, smin, smax):
         smin = jnp.where(can_add, jnp.minimum(smin, mnd), smin)
         mxd = jnp.roll(smax, d, axis=-1)
         smax = jnp.where(can_add, jnp.maximum(smax, mxd), smax)
-        f = f | (fd & ~edge) | edge
+        f = jnp.where(edge, 1, f | fd)
         d *= 2
     nxt = [jnp.roll(k, -1, axis=-1) for k in keys_lanes]
     tails = (~_lanes_eq(keys_lanes, nxt) | (idx == n - 1)) & valid
-    return cnt, ssum, smin, smax, tails
+    return cnt, ssum, smin, smax, tails.astype(jnp.int32)
 
 
 def _make_kernel(nlanes: int):
@@ -114,7 +136,7 @@ def _make_kernel(nlanes: int):
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def segmented_scan_tiles(keys, cnt, ssum, smin, smax, *, interpret: bool = True):
     """(T,N) key lane(s) / cnt and (T,V?,N) value tiles → scanned values +
-    tail mask.  ``keys`` is a (T,N) array (one lane) or a tuple of (T,N)
+    boolean tail mask.  ``keys`` is a (T,N) array (one lane) or a tuple of (T,N)
     uint32 lanes, hi lane first, for 64-bit keys."""
     keys_lanes = keys if isinstance(keys, (tuple, list)) else (keys,)
     keys_lanes = tuple(keys_lanes)
@@ -125,14 +147,14 @@ def segmented_scan_tiles(keys, cnt, ssum, smin, smax, *, interpret: bool = True)
         v = x.shape[1]
         return pl.BlockSpec((1, v, n), lambda i: (i, 0, 0))
 
-    out = pl.pallas_call(
+    *out, tails = pallas_call(
         _make_kernel(len(keys_lanes)),
         out_shape=(
             jax.ShapeDtypeStruct((t, n), cnt.dtype),
             jax.ShapeDtypeStruct(ssum.shape, ssum.dtype),
             jax.ShapeDtypeStruct(smin.shape, smin.dtype),
             jax.ShapeDtypeStruct(smax.shape, smax.dtype),
-            jax.ShapeDtypeStruct((t, n), jnp.bool_),
+            jax.ShapeDtypeStruct((t, n), jnp.int32),
         ),
         grid=(t,),
         in_specs=[spec1] * len(keys_lanes)
@@ -140,4 +162,4 @@ def segmented_scan_tiles(keys, cnt, ssum, smin, smax, *, interpret: bool = True)
         out_specs=(spec1, specv(ssum), specv(smin), specv(smax), spec1),
         interpret=interpret,
     )(*keys_lanes, cnt, ssum, smin, smax)
-    return out
+    return (*out, tails != 0)

@@ -23,6 +23,7 @@ import time
 import numpy as np
 
 from repro.core.types import ExecConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.service import AggregationService
 
 
@@ -137,6 +138,7 @@ def main():
     if args.smoke:
         kw.update(chunks=12, chunk_rows=512, snapshot_every=4,
                   memory_rows=256, batch_rows=64)
+    enable_compile_cache()
     r = serve(**kw)
     print(f"ingested {r['rows_ingested']} rows at "
           f"{r['ingest_rows_per_s'] / 1e6:.2f} M rows/s sustained")

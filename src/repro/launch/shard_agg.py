@@ -34,6 +34,8 @@ import os
 import sys
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def _zipf_keys(rng, n, domain, s, dtype):
     """Bounded-domain Zipf(s) draw: p(rank) ~ 1/rank**s over ``domain``
@@ -145,6 +147,7 @@ def main():
         os.environ["XLA_FLAGS"] = (
             f"{flags} --xla_force_host_platform_device_count="
             f"{args.fake_devices}").strip()
+    enable_compile_cache()
 
     if args.smoke:
         run(rows=4096, zipf=1.2, memory_rows=1024, batch_rows=256,

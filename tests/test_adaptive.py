@@ -82,6 +82,21 @@ def test_checked_in_constants_match_generator_schema():
     assert "cpu" in cc.COST_CONSTANTS, "CPU defaults must stay committed"
 
 
+def test_uncalibrated_backend_is_an_error():
+    """A platform without its own entry must not borrow another
+    platform's timings (the old silent fallback to ``cpu``)."""
+    import jax
+
+    from repro.core import _cost_constants as cc
+
+    assert cost_model.load_cost_constants(None) is cc.COST_CONSTANTS[
+        jax.default_backend()]
+    with pytest.raises(KeyError, match="no calibrated cost constants"):
+        cost_model.load_cost_constants("no-such-platform")
+    with pytest.raises(KeyError, match="no-such-platform"):
+        cost_model.cost_surface(1024, 256, backend="no-such-platform")
+
+
 def test_stale_constants_fail_loudly():
     bad = {"cpu": dict(make_constants())}
     del bad["cpu"]["merge_row_ns"]

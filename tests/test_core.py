@@ -201,3 +201,23 @@ def test_single_key_all_duplicates():
     assert stats.total_spill_rows == 0  # one group always fits memory
     assert int(st.occupancy()) == 1
     assert int(st.count[0]) == 30_000
+
+
+@pytest.mark.parametrize("dtype", [np.uint32, np.uint64])
+def test_key_dtype_context_scopes_64_bit_keys(dtype):
+    """uint64 keys get real 64-bit arrays inside the context only; uint32
+    keys leave JAX's dtype defaults alone."""
+    import jax
+
+    from repro.core.types import key_dtype_context
+
+    big = np.uint64(1) << np.uint64(40) if dtype == np.uint64 else None
+    with key_dtype_context(dtype):
+        x = jnp.asarray(np.arange(4, dtype=dtype))
+        assert x.dtype == jnp.dtype(dtype)
+        if big is not None:
+            y = jnp.asarray([big], dtype=jnp.uint64) + x[:1]
+            assert y.dtype == jnp.uint64 and int(y[0]) == int(big)
+            assert jax.config.jax_enable_x64
+    assert not jax.config.jax_enable_x64
+    assert jnp.asarray(np.arange(4, dtype=np.uint64)).dtype == jnp.uint32

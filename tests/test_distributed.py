@@ -201,7 +201,7 @@ def test_sparse_grad_compression_allreduce():
             out, _ = C.allreduce_topk(g[0], st, k=256, axis_name="data")
             return out[None]
 
-        from repro.distributed._compat import shard_map
+        from jax import shard_map
         fn = shard_map(local, mesh=mesh, in_specs=(P("data", None),),
                        out_specs=P("data", None))
         with mesh:

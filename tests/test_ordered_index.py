@@ -256,11 +256,9 @@ def test_registry_builtin_backends():
 
 
 def test_registry_auto_resolution():
-    name = dispatch.resolve_backend_name("auto")
-    assert name in dispatch.registered_backends()
-    # off-TPU, auto must prefer the XLA engine
-    if jax.default_backend() != "tpu":
-        assert name == "xla"
+    # auto is the XLA engine on every platform, TPU included
+    assert dispatch.resolve_backend_name("auto") == "xla"
+    assert dispatch.get_backend("auto") is dispatch.get_backend("xla")
 
 
 def test_registry_unknown_backend_raises():

@@ -200,7 +200,6 @@ def test_exchange_edge_geometry():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.distributed import groupby as gb
-        from repro.distributed._compat import shard_map
         from repro.core.types import EMPTY, empty_state
 
         mesh = jax.make_mesh((2,), ("data",))
@@ -228,9 +227,9 @@ def test_exchange_edge_geometry():
             spec = P("data")
             stacked = jax.tree.map(
                 lambda a, b: jnp.concatenate([a, b]), local_a, local_b)
-            fn = shard_map(f, mesh=mesh,
-                           in_specs=(spec,),
-                           out_specs=(spec, P(), P(), P()))
+            fn = jax.jit(jax.shard_map(f, mesh=mesh,
+                                       in_specs=(spec,),
+                                       out_specs=(spec, P(), P(), P())))
             return fn(stacked)
 
         # --- quota=1, world=2, shard B empty: one row per peer range ---
