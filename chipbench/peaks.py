@@ -1,0 +1,32 @@
+"""Published peaks of one chip, keyed by JAX's ``device_kind``.
+
+Source: Google Cloud documentation, "TPU v5e" (system architecture):
+197 TFLOP/s bf16, 393 TOP/s int8, 16 GB of HBM at 819 GB/s, 1,600 Gbit/s
+of inter-chip interconnect per chip.  A device kind missing from the
+table is an error, never a default.
+"""
+from __future__ import annotations
+
+PEAKS = {
+    "TPU v5 lite": {
+        "bf16_flops_per_s": 197e12,
+        "int8_ops_per_s": 393e12,
+        "hbm_bytes": 16e9,
+        "hbm_bytes_per_s": 819e9,
+        "ici_bits_per_s": 1600e9,
+        "source": "Google Cloud documentation, TPU v5e",
+    },
+}
+
+
+class UnknownDevice(KeyError):
+    pass
+
+
+def peaks(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise UnknownDevice(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)}") from None
