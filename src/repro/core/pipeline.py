@@ -69,6 +69,7 @@ from repro.core import dispatch
 from repro.core import merge as merge_mod
 from repro.core import run_generation as rg
 from repro.core import sorted_ops
+from repro.core.trace import span
 from repro.core.types import (
     AggState,
     DeviceSpillStats,
@@ -532,15 +533,17 @@ def _merge_phase(store, lens, spilled, nruns, overflow, *, page_rows: int,
     snapshot (which passes a fresh ``out_buffer`` so emission never
     aliases live engine state, plus the ``rows_retired`` accumulator)."""
     zero = jnp.int32(0)
-    store, lens, spill_m, msteps, mlevels = _device_premerge(
-        store, lens, fanin=fanin, levels=premerge_levels, backend=backend
-    )
-    out, out_cur, pages_read, max_occ, ix_overflow, dropped = (
-        merge_mod.wide_merge_device(
-            store, lens, page_rows=page_rows, index_rows=index_rows,
-            out_capacity=out_capacity, backend=backend, out=out_buffer,
+    with jax.named_scope("premerge"):
+        store, lens, spill_m, msteps, mlevels = _device_premerge(
+            store, lens, fanin=fanin, levels=premerge_levels, backend=backend
         )
-    )
+    with jax.named_scope("wide_merge"):
+        out, out_cur, pages_read, max_occ, ix_overflow, dropped = (
+            merge_mod.wide_merge_device(
+                store, lens, page_rows=page_rows, index_rows=index_rows,
+                out_capacity=out_capacity, backend=backend, out=out_buffer,
+            )
+        )
     # merge/emission stats are charged only when run generation actually
     # spilled — the in-memory case's pass through the merge is a formality
     # the host reference never pays (it returns the table directly).
@@ -591,22 +594,23 @@ def _pipeline_body(
     chunk, _, _, _ = _engine_geometry(policy, M, B, P)
     t = _num_batches(keys.shape[0], chunk)
     n_pad = t * chunk
-    bk, bp = _batch(keys, payload, chunk, t)
     width = 0 if payload is None else payload.shape[-1]
     ws = widths if widths is not None else (width, width, width)
     R = _stream_run_slots(policy, n_pad, M)
-    es = match_vma(_engine_init(policy, M=M, B=B, P=P, R=R, width=width,
-                                key_dtype=keys.dtype, widths=ws), keys)
 
     def body(carry, xs):
         ck, cp = xs
         return match_vma(_engine_step(carry, ck, cp, policy=policy, M=M, B=B,
                                       backend=backend, ws=ws), carry), None
 
-    es, _ = jax.lax.scan(body, es, (bk, bp))
-    store, lens, table, spilled, nruns, overflow = _engine_finish(
-        es, policy=policy, backend=backend
-    )
+    with jax.named_scope("rungen"):
+        bk, bp = _batch(keys, payload, chunk, t)
+        es = match_vma(_engine_init(policy, M=M, B=B, P=P, R=R, width=width,
+                                    key_dtype=keys.dtype, widths=ws), keys)
+        es, _ = jax.lax.scan(body, es, (bk, bp))
+        store, lens, table, spilled, nruns, overflow = _engine_finish(
+            es, policy=policy, backend=backend
+        )
 
     if not merge:
         zero = jnp.int32(0)
@@ -783,11 +787,13 @@ def _host_pad_flat(keys: np.ndarray, payload, total: int):
     n = keys.shape[0]
     if total == n:
         return keys, payload
-    keys = np.concatenate(
-        [keys, np.full(total - n, empty_key(keys.dtype), keys.dtype)])
-    if payload is not None:
-        payload = np.concatenate(
-            [payload, np.zeros((total - n,) + payload.shape[1:], payload.dtype)])
+    with span("repro.pad"):
+        keys = np.concatenate(
+            [keys, np.full(total - n, empty_key(keys.dtype), keys.dtype)])
+        if payload is not None:
+            payload = np.concatenate([
+                payload,
+                np.zeros((total - n,) + payload.shape[1:], payload.dtype)])
     return keys, payload
 
 
@@ -796,7 +802,7 @@ def _place_sharded(keys, payload, mesh, axis: str):
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
-    with key_dtype_context(np.dtype(keys.dtype)):
+    with key_dtype_context(np.dtype(keys.dtype)), span("repro.place"):
         return (jax.device_put(keys, NamedSharding(mesh, P(axis))),
                 jax.device_put(payload, NamedSharding(mesh, P(axis, None))))
 
@@ -841,7 +847,7 @@ def generate_runs_device(
     if payload is None:
         widths = (0, 0, 0) if widths is None else widths
     keys, payload = _host_pad_for_geometry(keys, payload, policy, cfg)
-    with key_dtype_context(np.dtype(keys.dtype)):
+    with key_dtype_context(np.dtype(keys.dtype)), span("repro.dispatch"):
         return _pipeline_jit(
             as_key_array(keys), payload, policy=policy,
             memory_rows=cfg.memory_rows, batch_rows=cfg.batch_rows,
@@ -915,7 +921,7 @@ def aggregate_device(
                                      cfg.batch_rows)
         pre = plan_pre_merge_levels(est, cfg, r_static)
         keys, payload = _host_pad_for_geometry(keys, payload, policy, cfg)
-        with key_dtype_context(np.dtype(keys.dtype)):
+        with key_dtype_context(np.dtype(keys.dtype)), span("repro.dispatch"):
             return _pipeline_jit(
                 as_key_array(keys), payload, policy=policy,
                 memory_rows=cfg.memory_rows, batch_rows=cfg.batch_rows,
@@ -946,7 +952,7 @@ def aggregate_device(
         fanin=cfg.fanin, premerge_levels=pre,
         backend=backend, widths=widths, exchange_quota=exchange_quota,
     )
-    with key_dtype_context(np.dtype(keys.dtype)):
+    with key_dtype_context(np.dtype(keys.dtype)), span("repro.dispatch"):
         return fn(as_key_array(keys), payload)
 
 
@@ -1031,50 +1037,53 @@ def insort_aggregate_device(
 def _absorb_chunk_body(es, bk, bp, *, policy, memory_rows, batch_rows,
                        backend, widths, local_slots, with_obs=False):
     TRACE_LOG.append(("absorb", policy, tuple(bk.shape), es.run_slots))
-    # The scan carries only a LOCAL window of the run store — the slots
-    # this chunk can actually reach (its exact run bound + the open
-    # slot), spliced back in one dynamic_update_slice.  Carrying the full
-    # store would make every scan step pay O(R) for the carry, i.e. each
-    # absorb would slow down as the stream grows; with the window the
-    # per-chunk cost is independent of how much has already streamed.
-    # The host grow schedule guarantees R >= ridx + local_slots, so the
-    # clamp below never actually moves the window over occupied slots.
-    R, L = es.run_slots, min(local_slots, es.run_slots)
-    ridx0 = jnp.clip(es.ridx, 0, R - L)
-    loc = dataclasses.replace(
-        es,
-        store=jax.tree.map(
-            lambda a: jax.lax.dynamic_slice_in_dim(a, ridx0, L, axis=0),
-            es.store),
-        lens=jax.lax.dynamic_slice_in_dim(es.lens, ridx0, L, axis=0),
-        ridx=es.ridx - ridx0,
-    )
+    with jax.named_scope("rungen"):
+        # The scan carries only a LOCAL window of the run store — the
+        # slots this chunk can actually reach (its exact run bound + the
+        # open slot), spliced back in one dynamic_update_slice.  Carrying
+        # the full store would make every scan step pay O(R) for the
+        # carry, i.e. each absorb would slow down as the stream grows;
+        # with the window the per-chunk cost is independent of how much
+        # has already streamed.  The host grow schedule guarantees
+        # R >= ridx + local_slots, so the clamp below never actually
+        # moves the window over occupied slots.
+        R, L = es.run_slots, min(local_slots, es.run_slots)
+        ridx0 = jnp.clip(es.ridx, 0, R - L)
+        loc = dataclasses.replace(
+            es,
+            store=jax.tree.map(
+                lambda a: jax.lax.dynamic_slice_in_dim(a, ridx0, L, axis=0),
+                es.store),
+            lens=jax.lax.dynamic_slice_in_dim(es.lens, ridx0, L, axis=0),
+            ridx=es.ridx - ridx0,
+        )
 
-    def body(carry, xs):
-        ck, cp = xs
-        return match_vma(_engine_step(carry, ck, cp, policy=policy,
-                                      M=memory_rows, B=batch_rows,
-                                      backend=backend, ws=widths), carry), None
+        def body(carry, xs):
+            ck, cp = xs
+            es_ = _engine_step(carry, ck, cp, policy=policy, M=memory_rows,
+                               B=batch_rows, backend=backend, ws=widths)
+            return match_vma(es_, carry), None
 
-    loc, _ = jax.lax.scan(body, loc, (bk, bp))
-    es = dataclasses.replace(
-        loc,
-        store=jax.tree.map(
-            lambda a, l: jax.lax.dynamic_update_slice_in_dim(
-                a, l, ridx0, axis=0),
-            es.store, loc.store),
-        lens=jax.lax.dynamic_update_slice_in_dim(es.lens, loc.lens, ridx0,
-                                                 axis=0),
-        ridx=ridx0 + loc.ridx,
-    )
-    if not with_obs:
-        return es
-    # adaptive streams get the governor's decision scalars as an extra
-    # output of the SAME program: a separate _observe dispatch would hold
-    # a pending read on the engine buffers and force the next (donating)
-    # absorb into a defensive copy of the whole state — folded in here,
-    # donation stays clean and the observation is free.
-    return es, _observe_body(es)
+        loc, _ = jax.lax.scan(body, loc, (bk, bp))
+        es = dataclasses.replace(
+            loc,
+            store=jax.tree.map(
+                lambda a, l: jax.lax.dynamic_update_slice_in_dim(
+                    a, l, ridx0, axis=0),
+                es.store, loc.store),
+            lens=jax.lax.dynamic_update_slice_in_dim(es.lens, loc.lens,
+                                                     ridx0, axis=0),
+            ridx=ridx0 + loc.ridx,
+        )
+        if not with_obs:
+            return es
+        # adaptive streams get the governor's decision scalars as an
+        # extra output of the SAME program: a separate _observe dispatch
+        # would hold a pending read on the engine buffers and force the
+        # next (donating) absorb into a defensive copy of the whole state
+        # — folded in here, donation stays clean and the observation is
+        # free.
+        return es, _observe_body(es)
 
 
 _absorb_chunk = jax.jit(
@@ -1136,14 +1145,14 @@ def _finalize_stream_body(es, retired, *, policy, page_rows, index_rows,
     ``retired`` threads the service's eviction accumulator into the
     stats (``None`` when no eviction ever ran)."""
     TRACE_LOG.append(("finalize", policy, out_capacity))
-    es = _trim_slots(es, trim)
     ws = (es.store.sum.shape[-1], es.store.min.shape[-1],
           es.store.max.shape[-1])  # store planes are stacked (R, C, w)
     fresh_out = empty_state(out_capacity, max(ws), key_dtype=es.key_dtype,
                             widths=ws)
-    store, lens, table, spilled, nruns, overflow = _engine_finish(
-        es, policy=policy, backend=backend
-    )
+    with jax.named_scope("rungen"):
+        store, lens, table, spilled, nruns, overflow = _engine_finish(
+            _trim_slots(es, trim), policy=policy, backend=backend
+        )
     return _merge_phase(
         store, lens, spilled, nruns, overflow, page_rows=page_rows,
         index_rows=index_rows, fanin=fanin, premerge_levels=premerge_levels,
@@ -1622,8 +1631,9 @@ class StreamingAggregator:
         the transfer behind compute.  Empty chunks return None."""
         if np.asarray(keys).shape[0] == 0:
             return None
-        bk, bp, n, n_pad = self._prep(keys, payload)
-        with key_dtype_context(self.key_dtype):
+        with span("repro.pad"):
+            bk, bp, n, n_pad = self._prep(keys, payload)
+        with key_dtype_context(self.key_dtype), span("repro.place"):
             if self.mesh is not None:
                 from jax.sharding import NamedSharding
                 from jax.sharding import PartitionSpec as P
@@ -1677,7 +1687,7 @@ class StreamingAggregator:
             self._rows_since_evict + staged.rows_padded, staged.rows_padded
         )
         local = self._local_slots(staged.rows_padded)
-        with key_dtype_context(self.key_dtype):
+        with key_dtype_context(self.key_dtype), span("repro.dispatch"):
             if self._es is None:
                 self._R = needed
                 if self.mesh is None:
@@ -1768,7 +1778,8 @@ class StreamingAggregator:
 
         if self._es is None:
             return adaptive_mod.Observation(0, 0, 0, 0, 0)
-        with key_dtype_context(self.key_dtype):
+        with key_dtype_context(self.key_dtype), \
+                span("repro.readback", what="observation"):
             vec = jax.device_get(_observe(self._es))
         self.readbacks_paid += 1
         return adaptive_mod.Observation(
@@ -1793,7 +1804,8 @@ class StreamingAggregator:
         self._pending_obs = self._last_obs_vec
         if pending is None:
             return  # first boundary: the observation pipeline is priming
-        vec = jax.device_get(pending)
+        with span("repro.readback", what="observation"):
+            vec = jax.device_get(pending)
         self.readbacks_paid += 1
         obs = adaptive_mod.Observation(
             rows_absorbed=int(vec[0]), dup_rows=int(vec[1]),
@@ -1896,7 +1908,9 @@ class StreamingAggregator:
             entry_point, out_cap, err, out_cap2, pre + 1,
         )
         state, dstats = self._run_merge(es, pre + 1, out_cap2, trim)
-        return state, dstats.finalize(entry_point=entry_point)
+        stats = dstats.finalize(entry_point=entry_point)
+        return state, dataclasses.replace(
+            stats, merge_retries=stats.merge_retries + 1)
 
     def _retry_exchange(self, entry_point: str, err, es,
                         pre: int, out_cap: int, trim: int):
@@ -1973,7 +1987,7 @@ class StreamingAggregator:
         """Dispatch the (non-donating) drain + merge program on ``es``.
         ``exchange_quota`` overrides the mesh exchange's derived per-peer
         quota (the :meth:`_retry_exchange` path)."""
-        with key_dtype_context(self.key_dtype):
+        with key_dtype_context(self.key_dtype), span("repro.dispatch"):
             if self.mesh is None:
                 return _finalize_stream(
                     es, self._retired, policy=self._arm,
@@ -2389,9 +2403,10 @@ def _mesh_stream_fns(
             es = _trim_slots(squeeze_engine_scalars(es), trim)
             fresh_out = empty_state(out_capacity, width, key_dtype=kd,
                                     widths=widths)
-            store, lens, table, spilled, nruns, overflow = _engine_finish(
-                es, policy=policy, backend=backend
-            )
+            with jax.named_scope("rungen"):
+                store, lens, table, spilled, nruns, overflow = _engine_finish(
+                    es, policy=policy, backend=backend
+                )
             # per-shard retired rows go into the stats BEFORE cross_shard
             # psums them into the global total
             retired = rest[0][0] if with_retired else None

@@ -52,6 +52,7 @@ from repro.core import hash_agg as hash_mod
 from repro.core import insort as insort_mod
 from repro.core import merge_join as mj_mod
 from repro.core import sorted_ops
+from repro.core.trace import span
 from repro.core.types import (
     AggState,
     ExecConfig,
@@ -180,22 +181,26 @@ class KeySpec:
         flag here.  ``validate=True`` checks every column against its bit
         budget and rejects the reserved EMPTY bit pattern.
         """
-        cols = self._as_columns(columns)
-        out = np.zeros(cols[0].shape, dtype=np.uint64)
-        for spec, col in zip(self.columns, cols):
-            col = col.astype(np.uint64)
-            if validate and col.size and int(col.max()) > spec.max_value:
+        with span("repro.pack"):
+            cols = self._as_columns(columns)
+            out = np.zeros(cols[0].shape, dtype=np.uint64)
+            for spec, col in zip(self.columns, cols):
+                col = col.astype(np.uint64)
+                if validate and col.size and int(col.max()) > spec.max_value:
+                    raise ValueError(
+                        f"column {spec.name!r} exceeds its {spec.bits}-bit "
+                        f"budget (max value {int(col.max())} > "
+                        f"{spec.max_value})"
+                    )
+                out = (out << np.uint64(spec.bits)) | col
+            packed = out.astype(self.key_dtype)
+            if (validate and packed.size
+                    and bool((packed == self.empty).any())):
                 raise ValueError(
-                    f"column {spec.name!r} exceeds its {spec.bits}-bit budget "
-                    f"(max value {int(col.max())} > {spec.max_value})"
+                    "the all-ones column combination packs to the reserved "
+                    "EMPTY sentinel; reduce a column's max value or widen a "
+                    "column"
                 )
-            out = (out << np.uint64(spec.bits)) | col
-        packed = out.astype(self.key_dtype)
-        if validate and packed.size and bool((packed == self.empty).any()):
-            raise ValueError(
-                "the all-ones column combination packs to the reserved EMPTY "
-                "sentinel; reduce a column's max value or widen a column"
-            )
         return packed
 
     def unpack(self, keys) -> dict[str, np.ndarray]:
@@ -871,6 +876,10 @@ def _plan(
     }
 
 
+# ids of the one-shot queries, for the repro.aggregate span's query=
+_query_ids = itertools.count()
+
+
 def aggregate(
     columns,
     *,
@@ -965,66 +974,70 @@ def aggregate(
             "input (pass an iterator of column batches); one-shot input "
             "is planned up front with algorithm='auto'"
         )
-    packed = by.pack(columns)
-    want_sorted = _resolve_order_by(order_by, by)
-    if values is not None:
-        values = np.asarray(values, dtype=np.float32)
-        if values.ndim == 1:
-            values = values[:, None]
-        widths = aggs.plane_widths(values.shape[1])
-        if not any(widths):
-            values = None  # nothing requested needs the payload
-            widths = (0, 0, 0)
-    else:
-        widths = (0, 0, 0)
-        if aggs.needs_payload():
-            raise ValueError(
-                f"aggregates {aggs.names} need a payload; pass values=..."
-            )
-    plan = _plan(len(packed), cfg, output_estimate, input_sorted=input_sorted)
-    backend = dispatch.resolve_backend_name(backend)
-    plan["backend"] = backend
-
-    sort_based = algorithm in ("auto", "insort", "sort_then_stream", "inmemory")
-    plan["algorithm"] = "insort" if algorithm == "auto" else algorithm
-    plan["pipeline"] = pipeline if algorithm in ("auto", "insort") else "host"
-    if mesh is not None:
-        from repro.core.pipeline import resolve_mesh_axis
-
-        axis = resolve_mesh_axis(mesh, mesh_axis)
-        plan["mesh"] = {"axis": axis, "world": int(mesh.shape[axis])}
-    with key_dtype_context(by.key_dtype):
-        if algorithm in ("auto", "insort"):
-            state, stats = insort_mod.insort_aggregate(
-                packed, values, cfg, output_estimate=output_estimate,
-                backend=backend, widths=widths, pipeline=pipeline,
-                mesh=mesh, mesh_axis=mesh_axis,
-            )
-        elif algorithm == "sort_then_stream":
-            state, stats = insort_mod.sort_then_stream_aggregate(
-                packed, values, cfg, backend=backend
-            )
-        elif algorithm == "hash":
-            state, stats = hash_mod.hash_aggregate(
-                packed, values, cfg, output_estimate=output_estimate,
-                backend=backend, widths=widths,
-            )
-        elif algorithm == "f1_hash":
-            state, stats = hash_mod.f1_hash_aggregate(
-                packed, values, cfg, backend=backend, widths=widths
-            )
-        elif algorithm == "inmemory":
-            state = sorted_ops.sorted_groupby(
-                packed, values, backend=backend, widths=widths
-            )
-            stats = SpillStats()
+    with span("repro.aggregate", query=next(_query_ids)):
+        packed = by.pack(columns)
+        want_sorted = _resolve_order_by(order_by, by)
+        if values is not None:
+            values = np.asarray(values, dtype=np.float32)
+            if values.ndim == 1:
+                values = values[:, None]
+            widths = aggs.plane_widths(values.shape[1])
+            if not any(widths):
+                values = None  # nothing requested needs the payload
+                widths = (0, 0, 0)
         else:
-            raise ValueError(f"unknown algorithm {algorithm!r}")
-        if want_sorted and not sort_based:
-            # hash order → key order: the extra sort the paper's operator
-            # never pays (Fig 19)
-            state = sorted_ops.sort_state(state, backend=backend)
-    return AggResult(state=state, stats=stats, by=by, aggs=aggs, plan=plan)
+            widths = (0, 0, 0)
+            if aggs.needs_payload():
+                raise ValueError(
+                    f"aggregates {aggs.names} need a payload; pass values=..."
+                )
+        plan = _plan(len(packed), cfg, output_estimate,
+                     input_sorted=input_sorted)
+        backend = dispatch.resolve_backend_name(backend)
+        plan["backend"] = backend
+
+        sort_based = algorithm in ("auto", "insort", "sort_then_stream",
+                                   "inmemory")
+        plan["algorithm"] = "insort" if algorithm == "auto" else algorithm
+        plan["pipeline"] = (pipeline if algorithm in ("auto", "insort")
+                            else "host")
+        if mesh is not None:
+            from repro.core.pipeline import resolve_mesh_axis
+
+            axis = resolve_mesh_axis(mesh, mesh_axis)
+            plan["mesh"] = {"axis": axis, "world": int(mesh.shape[axis])}
+        with key_dtype_context(by.key_dtype):
+            if algorithm in ("auto", "insort"):
+                state, stats = insort_mod.insort_aggregate(
+                    packed, values, cfg, output_estimate=output_estimate,
+                    backend=backend, widths=widths, pipeline=pipeline,
+                    mesh=mesh, mesh_axis=mesh_axis,
+                )
+            elif algorithm == "sort_then_stream":
+                state, stats = insort_mod.sort_then_stream_aggregate(
+                    packed, values, cfg, backend=backend
+                )
+            elif algorithm == "hash":
+                state, stats = hash_mod.hash_aggregate(
+                    packed, values, cfg, output_estimate=output_estimate,
+                    backend=backend, widths=widths,
+                )
+            elif algorithm == "f1_hash":
+                state, stats = hash_mod.f1_hash_aggregate(
+                    packed, values, cfg, backend=backend, widths=widths
+                )
+            elif algorithm == "inmemory":
+                state = sorted_ops.sorted_groupby(
+                    packed, values, backend=backend, widths=widths
+                )
+                stats = SpillStats()
+            else:
+                raise ValueError(f"unknown algorithm {algorithm!r}")
+            if want_sorted and not sort_based:
+                # hash order → key order: the extra sort the paper's operator
+                # never pays (Fig 19)
+                state = sorted_ops.sort_state(state, backend=backend)
+        return AggResult(state=state, stats=stats, by=by, aggs=aggs, plan=plan)
 
 
 def _aggregate_stream(

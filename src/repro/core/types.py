@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.trace import span
+
 EMPTY = np.uint32(0xFFFFFFFF)
 # Largest key a user may supply (EMPTY is reserved).
 MAX_KEY = np.uint32(0xFFFFFFFE)
@@ -405,6 +407,9 @@ class SpillStats:
     exchange_quota: int = 0
     exchange_max_fill: int = 0
     exchange_retries: int = 0
+    # how many times a snapshot or finalize had to re-run its merge at a
+    # wider output capacity (the merge-overflow retry; 0 for one-shot plans)
+    merge_retries: int = 0
 
     @property
     def total_spill_rows(self) -> int:
@@ -442,6 +447,7 @@ class SpillStats:
             exchange_quota=max(s.exchange_quota for s in shards),
             exchange_max_fill=max(s.exchange_max_fill for s in shards),
             exchange_retries=sum(s.exchange_retries for s in shards),
+            merge_retries=sum(s.merge_retries for s in shards),
         )
 
 
@@ -558,53 +564,54 @@ class DeviceSpillStats:
         merge-on-read service query) so an overflow raised here tells the
         caller which knob to turn.
         """
-        if bool(self.run_buffer_overflowed):
-            raise RuntimeError(
-                f"device run buffer overflowed its preallocated run slots "
-                f"during {entry_point}; results would be missing rows "
-                "(this is a bug in the slot bound — please report input "
-                "sizes and ExecConfig)"
-            )
-        if bool(self.exchange_dropped):
-            raise ExchangeOverflowError(
-                f"the cross-shard exchange during {entry_point} overflowed "
-                f"its per-peer send quota ({int(self.exchange_max_fill)} "
-                f"rows in the fullest segment vs quota "
-                f"{int(self.exchange_quota)}); rows would have been left "
-                "behind — pass a larger exchange_quota (host entry points "
-                "retry once at the next pow2 automatically)",
-                quota=int(self.exchange_quota),
-                max_fill=int(self.exchange_max_fill),
-            )
-        if bool(self.merge_dropped_rows):
-            if entry_point == "snapshot":
-                hint = (
-                    "raise output_rows (the snapshot output capacity) or "
-                    "pass a larger output_estimate (more pre-merge levels)"
+        with span("repro.readback", what="stats"):
+            if bool(self.run_buffer_overflowed):
+                raise RuntimeError(
+                    f"device run buffer overflowed its preallocated run slots "
+                    f"during {entry_point}; results would be missing rows "
+                    "(this is a bug in the slot bound — please report input "
+                    "sizes and ExecConfig)"
                 )
-            else:
-                hint = (
-                    "pass a larger output_estimate (more pre-merge levels) "
-                    "or raise index_rows"
+            if bool(self.exchange_dropped):
+                raise ExchangeOverflowError(
+                    f"the cross-shard exchange during {entry_point} overflowed "
+                    f"its per-peer send quota ({int(self.exchange_max_fill)} "
+                    f"rows in the fullest segment vs quota "
+                    f"{int(self.exchange_quota)}); rows would have been left "
+                    "behind — pass a larger exchange_quota (host entry points "
+                    "retry once at the next pow2 automatically)",
+                    quota=int(self.exchange_quota),
+                    max_fill=int(self.exchange_max_fill),
                 )
-            raise MergeOverflowError(
-                f"the wide merge during {entry_point} dropped rows: either "
-                "its index overflowed its capacity (max resident "
-                f"{int(self.max_index_occupancy)} rows) or the output "
-                f"overran its buffer — {hint}"
+            if bool(self.merge_dropped_rows):
+                if entry_point == "snapshot":
+                    hint = (
+                        "raise output_rows (the snapshot output capacity) or "
+                        "pass a larger output_estimate (more pre-merge levels)"
+                    )
+                else:
+                    hint = (
+                        "pass a larger output_estimate (more pre-merge levels) "
+                        "or raise index_rows"
+                    )
+                raise MergeOverflowError(
+                    f"the wide merge during {entry_point} dropped rows: either "
+                    "its index overflowed its capacity (max resident "
+                    f"{int(self.max_index_occupancy)} rows) or the output "
+                    f"overran its buffer — {hint}"
+                )
+            return SpillStats(
+                rows_spilled_run_generation=int(self.rows_spilled_run_generation),
+                rows_spilled_merge=int(self.rows_spilled_merge),
+                runs_generated=int(self.runs_generated),
+                merge_steps=int(self.merge_steps),
+                merge_levels=int(self.merge_levels),
+                pages_read=int(self.pages_read),
+                rows_emitted=int(self.rows_emitted),
+                index_overflowed=bool(self.index_overflowed),
+                max_index_occupancy=int(self.max_index_occupancy),
+                rows_exchanged=int(self.rows_exchanged),
+                rows_retired=int(self.rows_retired),
+                exchange_quota=int(self.exchange_quota),
+                exchange_max_fill=int(self.exchange_max_fill),
             )
-        return SpillStats(
-            rows_spilled_run_generation=int(self.rows_spilled_run_generation),
-            rows_spilled_merge=int(self.rows_spilled_merge),
-            runs_generated=int(self.runs_generated),
-            merge_steps=int(self.merge_steps),
-            merge_levels=int(self.merge_levels),
-            pages_read=int(self.pages_read),
-            rows_emitted=int(self.rows_emitted),
-            index_overflowed=bool(self.index_overflowed),
-            max_index_occupancy=int(self.max_index_occupancy),
-            rows_exchanged=int(self.rows_exchanged),
-            rows_retired=int(self.rows_retired),
-            exchange_quota=int(self.exchange_quota),
-            exchange_max_fill=int(self.exchange_max_fill),
-        )

@@ -284,12 +284,14 @@ def exchange_and_merge(st: AggState, axis: str, world: int, *,
     ``world * quota`` and an :class:`ExchangeInfo`."""
     if quota is None:
         quota = default_exchange_quota(st.capacity, world)
-    recv, rows_sent, send_dropped, max_fill = exchange_sorted_fragments(
-        st, axis, world, quota=quota
-    )
-    merged, merge_dropped = merge_received_fragments(
-        recv, world, quota, backend=backend, page_rows=page_rows
-    )
+    with jax.named_scope("exchange"):
+        recv, rows_sent, send_dropped, max_fill = exchange_sorted_fragments(
+            st, axis, world, quota=quota
+        )
+    with jax.named_scope("owner_merge"):
+        merged, merge_dropped = merge_received_fragments(
+            recv, world, quota, backend=backend, page_rows=page_rows
+        )
     return merged, ExchangeInfo(rows_sent, send_dropped, max_fill,
                                 merge_dropped, quota)
 

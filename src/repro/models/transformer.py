@@ -95,8 +95,8 @@ def apply_stack(params, cfg: ModelConfig, kind: str, x, positions, *,
                 caches=None, mrope_pos=None, dispatch=None):
     """Apply a homogeneous stack: lax.scan over stacked (L, …) params by
     default (flat compile time in depth), or an unrolled python loop when
-    ``cfg.scan_layers=False`` (used by the roofline calibration, where XLA
-    cost_analysis must see every layer)."""
+    ``cfg.scan_layers=False`` (for XLA cost_analysis, which counts a
+    scanned body once and must see every layer)."""
 
     def body(carry, xs):
         h, aux = carry

@@ -27,6 +27,7 @@ import jax
 import numpy as np
 
 from repro.core.pipeline import StreamingAggregator
+from repro.core.trace import span
 from repro.core.types import (
     AggState,
     DeviceSpillStats,
@@ -164,8 +165,9 @@ class AggregationService:
         state, stats = self._agg.snapshot()
         jax.block_until_ready(state.keys)
         seconds = time.perf_counter() - t0
-        self.metrics.observe_snapshot(
-            stats, groups=int(state.occupancy()), seconds=seconds)
+        with span("repro.readback", what="occupancy"):
+            groups = int(state.occupancy())
+        self.metrics.observe_snapshot(stats, groups=groups, seconds=seconds)
         self.metrics.observe_policy(
             self._agg.policy_events, readbacks=self._agg.readbacks_paid,
             current=self._agg.arm)

@@ -17,6 +17,7 @@ import numpy as np
 from repro.core import dispatch
 from repro.core import schema as schema_mod
 from repro.core.schema import AggResult, AggSpec, KeySpec
+from repro.core.trace import span
 from repro.core.types import ExecConfig, SpillStats, empty_state, key_dtype_context
 from repro.service.metrics import ServiceMetrics
 from repro.service.service import AggregationService
@@ -156,11 +157,12 @@ class AggregationSession:
         """Absorb one column-batch mapping (key columns named by the
         KeySpec, plus the values column when requested)."""
         self._check_open()
-        packed, vals = self._prep(batch)
-        if not len(packed):
-            return
-        svc = self._ensure_service(0 if vals is None else vals.shape[1])
-        svc.ingest(packed, vals)
+        with span("repro.ingest", chunk=self.metrics.chunks_ingested):
+            packed, vals = self._prep(batch)
+            if not len(packed):
+                return
+            svc = self._ensure_service(0 if vals is None else vals.shape[1])
+            svc.ingest(packed, vals)
 
     def snapshot(self) -> AggResult:
         """Merge-on-read snapshot as a sorted :class:`AggResult`.
@@ -176,7 +178,8 @@ class AggregationSession:
                     0, 0, key_dtype=self.by.key_dtype,
                     widths=self.aggs.plane_widths(0))
             return self._result(state, SpillStats())
-        state, stats = self._svc.snapshot()
+        with span("repro.snapshot", snapshot=self.metrics.snapshots_taken):
+            state, stats = self._svc.snapshot()
         return self._result(state, stats)
 
     def expire_below(self, cutoff=None, **by_name) -> int:
@@ -224,7 +227,8 @@ class AggregationSession:
                     0, 0, key_dtype=self.by.key_dtype,
                     widths=self.aggs.plane_widths(0))
             return self._result(state, SpillStats())
-        state, stats = self._svc.close()
+        with span("repro.close"):
+            state, stats = self._svc.close()
         return self._result(state, stats)
 
 

@@ -7,7 +7,7 @@ accumulate.  Dropping JAX's compilation caches between test modules keeps
 the live-executable set bounded (each module re-compiles what it needs).
 
 NOTE: deliberately no XLA_FLAGS here — tests must see 1 device; the
-dry-run module and the multi-device subprocess tests set their own.
+multi-device subprocess tests set their own.
 """
 import jax
 import pytest

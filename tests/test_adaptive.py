@@ -453,6 +453,19 @@ def test_snapshot_retries_once_and_engine_survives(caplog):
     validate_against_oracle(state2, np.concatenate([keys, more]))
 
 
+@pytest.mark.parametrize("entry", ("snapshot", "finalize"))
+@pytest.mark.parametrize("n_unique,retries", ((12, 0), (24, 1)))
+def test_merge_retry_is_counted(entry, n_unique, retries):
+    """A snapshot's or finalize's merge re-run at the wider capacity shows
+    as ``SpillStats.merge_retries``, as an exchange retry shows as
+    ``exchange_retries``."""
+    agg, keys = _overflow_agg(n_unique)
+    state, stats = getattr(agg, entry)()
+    validate_against_oracle(state, keys)
+    assert stats.merge_retries == retries
+    assert stats.exchange_retries == 0
+
+
 def test_retry_that_still_overflows_raises():
     agg, _keys = _overflow_agg(512)  # 512 uniques >> 32: retry can't save it
     with pytest.raises(MergeOverflowError, match="finalize"):
